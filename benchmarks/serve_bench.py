@@ -205,7 +205,7 @@ def run(quick: bool = True, dtype: str = "bfloat16"):
         # exact-zero trajectory counter (check_trajectory COUNT_FIELDS).
         from repro.launch.cnn_serve import CNNServer, ImageRequest
         from repro.runtime.resilience import FaultInjector
-        srv = CNNServer(name, max_bucket=8, impl="xla",
+        srv = CNNServer(name, reduced=True, max_bucket=8, impl="xla",
                         calibration="analytic",
                         injector=FaultInjector(seed=0,
                                                rates={"kernel": 0.5}))

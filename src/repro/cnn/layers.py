@@ -30,7 +30,7 @@ _DIMNUMS = {
 
 
 def conv_forward(x, w, layout: str, stride: int = 1, pad: int = 0,
-                 impl: str = "xla", interpret: bool = True):
+                 impl: str = "xla", interpret: Optional[bool] = None):
     """x in ``layout``; w canonical [Co, Ci, F, F].
 
     int8 ``x`` (mixed-dtype storage, DESIGN.md §9) is consumed natively by
@@ -51,16 +51,12 @@ def conv_forward(x, w, layout: str, stride: int = 1, pad: int = 0,
         return lax.conv_general_dilated(
             x.astype(cdt), wr.astype(cdt), (stride, stride),
             [(pad, pad), (pad, pad)], dimension_numbers=(lhs, rhs, out),
+            precision=_precision(cdt),
             preferred_element_type=jnp.float32).astype(cdt)
     if impl == "pallas":
-        if layout == "CHWN":
-            from repro.kernels.conv.ops import conv_direct_chwn
-            wr = jnp.transpose(w, (1, 2, 3, 0))
-            return conv_direct_chwn(x, wr.astype(cdt), stride=stride,
-                                    pad=pad, interpret=interpret)
-        from repro.kernels.conv.ops import conv_im2col_nchw_fused
-        return conv_im2col_nchw_fused(x, w.astype(cdt), stride=stride,
-                                      pad=pad, interpret=interpret)
+        from repro.kernels.conv.ops import conv_fused
+        return conv_fused(x, w.astype(cdt), stride=stride, pad=pad,
+                          engine=layout, interpret=interpret)
     if impl == "fft":
         assert layout == "NCHW", "FFT conv is bound to NCHW (paper §IV.A)"
         from repro.kernels.conv.ops import conv_fft_nchw
@@ -70,14 +66,13 @@ def conv_forward(x, w, layout: str, stride: int = 1, pad: int = 0,
 
 
 def pool_forward(x, layout: str, F: int, S: int, op: str = "max",
-                 impl: str = "xla", interpret: bool = True,
+                 impl: str = "xla", interpret: Optional[bool] = None,
                  dst_layout: Optional[str] = None):
     dst = dst_layout or layout
     if impl == "pallas":
-        from repro.kernels.pool.ops import pool_chwn, pool_nchw
-        if layout == "CHWN":
-            return pool_chwn(x, F, S, op, dst_layout=dst, interpret=interpret)
-        return pool_nchw(x, F, S, op, dst_layout=dst, interpret=interpret)
+        from repro.kernels.pool.ops import pool_fused
+        return pool_fused(x, F, S, op, layout=layout, dst_layout=dst,
+                          interpret=interpret)
     from repro.kernels.pool.ref import pool_ref
     y = pool_ref(x, F, S, op, layout)
     if dst != layout:
@@ -92,7 +87,7 @@ def fused_conv_block(x, w, layout: str, stride: int = 1, pad: int = 0, *,
                      res=None, res_layout: Optional[str] = None,
                      src_layout: Optional[str] = None,
                      dst_layout: Optional[str] = None,
-                     impl: str = "pallas", interpret: bool = True):
+                     impl: str = "pallas", interpret: Optional[bool] = None):
     """One fused-engine node: conv[+bias][+residual add][+relu][+pool]
     executed natively in ``layout``, consuming ``src_layout`` input and
     producing ``dst_layout`` output.  ``res`` is the skip tensor of a folded
@@ -105,20 +100,12 @@ def fused_conv_block(x, w, layout: str, stride: int = 1, pad: int = 0, *,
     dst = dst_layout or layout
     cdt = w.dtype if x.dtype == jnp.int8 else x.dtype  # compute/out dtype
     if impl == "pallas":
-        if layout == "CHWN":
-            from repro.kernels.conv.ops import conv_direct_chwn
-            wr = jnp.transpose(w, (1, 2, 3, 0)).astype(cdt)
-            return conv_direct_chwn(x, wr, stride=stride, pad=pad,
-                                    interpret=interpret, bias=bias, relu=relu,
-                                    pool=pool, res=res,
-                                    res_layout=res_layout or layout,
-                                    src_layout=src, dst_layout=dst)
-        from repro.kernels.conv.ops import conv_im2col_nchw_fused
-        return conv_im2col_nchw_fused(x, w.astype(cdt), stride=stride,
-                                      pad=pad, interpret=interpret, bias=bias,
-                                      relu=relu, pool=pool, res=res,
-                                      res_layout=res_layout or layout,
-                                      src_layout=src, dst_layout=dst)
+        from repro.kernels.conv.ops import conv_fused
+        return conv_fused(x, w.astype(cdt), stride=stride, pad=pad,
+                          engine=layout, interpret=interpret, bias=bias,
+                          relu=relu, pool=pool, res=res,
+                          res_layout=res_layout or layout,
+                          src_layout=src, dst_layout=dst)
     from repro.core.transform import apply_transform
     y = apply_transform(x.astype(cdt), src, layout)
     y = conv_forward(y, w, layout, stride, pad, impl="xla")
@@ -143,7 +130,7 @@ def fused_conv_stack(x, w1, w2, layout: str, stride1: int = 1, pad1: int = 0,
                      res=None, res_layout: Optional[str] = None,
                      src_layout: Optional[str] = None,
                      dst_layout: Optional[str] = None, nt: int = 8,
-                     impl: str = "pallas", interpret: bool = True):
+                     impl: str = "pallas", interpret: Optional[bool] = None):
     """Cross-layer stack node (DESIGN.md §12): conv1[+relu]->conv2[+residual
     add][+relu][+pool] executed natively in ``layout`` as ONE kernel — the
     intermediate activation between the convs is staged in VMEM and never
@@ -155,21 +142,12 @@ def fused_conv_stack(x, w1, w2, layout: str, stride1: int = 1, pad1: int = 0,
     src = src_layout or layout
     dst = dst_layout or layout
     if impl == "pallas":
-        if layout == "CHWN":
-            from repro.kernels.conv.ops import conv_stack_chwn
-            w1r = jnp.transpose(w1, (1, 2, 3, 0))    # [Ci,F1,F1,Cm]
-            w2r = jnp.transpose(w2, (1, 2, 3, 0))    # [Cm,F2,F2,Co]
-            return conv_stack_chwn(x, w1r, w2r, stride1, pad1, stride2,
-                                   pad2, nt, interpret, relu1=relu1,
-                                   relu2=relu2, pool=pool, res=res,
-                                   res_layout=res_layout or layout,
-                                   src_layout=src, dst_layout=dst)
-        from repro.kernels.conv.ops import conv_stack_nchw
-        return conv_stack_nchw(x, w1, w2, stride1, pad1, stride2, pad2,
-                               interpret, relu1=relu1, relu2=relu2,
-                               pool=pool, res=res,
-                               res_layout=res_layout or layout,
-                               src_layout=src, dst_layout=dst)
+        from repro.kernels.conv.ops import conv_stack
+        return conv_stack(x, w1, w2, stride1, pad1, stride2, pad2,
+                          engine=layout, nt=nt, interpret=interpret,
+                          relu1=relu1, relu2=relu2, pool=pool, res=res,
+                          res_layout=res_layout or layout,
+                          src_layout=src, dst_layout=dst)
     y = fused_conv_block(x, w1, layout, stride1, pad1, relu=relu1,
                          src_layout=src, impl="xla")
     return fused_conv_block(y, w2, layout, stride2, pad2, relu=relu2,
@@ -186,14 +164,21 @@ def flatten_forward(x, layout: str):
     return x.reshape(N, -1)
 
 
+def _precision(dtype):
+    """fp32 operands multiply at full f32 precision (on a TPU the default
+    is one bf16 MXU pass); narrower dtypes take the default."""
+    return lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
 def fc_forward(x2d, w, b):
     """y = xW + b with f32 MXU accumulation, emitted in the storage dtype
     (the cuDNN mixed-precision recipe: narrow storage, wide accumulate)."""
-    y = jnp.dot(x2d, w, preferred_element_type=jnp.float32)
+    y = jnp.dot(x2d, w, preferred_element_type=jnp.float32,
+                precision=_precision(jnp.result_type(x2d, w)))
     return (y + b.astype(jnp.float32)).astype(x2d.dtype)
 
 
-def softmax_forward(x2d, impl: str = "xla", interpret: bool = True):
+def softmax_forward(x2d, impl: str = "xla", interpret: Optional[bool] = None):
     if impl == "pallas":
         from repro.kernels.softmax.ops import softmax as softmax_fused
         return softmax_fused(x2d, interpret=interpret)

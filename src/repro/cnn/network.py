@@ -207,7 +207,7 @@ def _acct_pool(stats, in_b, out_b, training):
 
 
 def forward(params: Dict, x_nchw, cfg: CNNConfig, layouts: List[str],
-            impl: str = "xla", interpret: bool = True,
+            impl: str = "xla", interpret: Optional[bool] = None,
             use_pallas_transform: bool = False, training: bool = False,
             cost_model: Optional[CostModel] = None
             ) -> Tuple[jnp.ndarray, RunStats]:
@@ -309,7 +309,7 @@ def forward(params: Dict, x_nchw, cfg: CNNConfig, layouts: List[str],
 
 
 def forward_fused(params: Dict, x_nchw, cfg: CNNConfig, plan: FusedPlan,
-                  impl: str = "pallas", interpret: bool = True,
+                  impl: str = "pallas", interpret: Optional[bool] = None,
                   training: bool = False,
                   cost_model: Optional[CostModel] = None
                   ) -> Tuple[jnp.ndarray, RunStats]:
@@ -552,7 +552,7 @@ def make_train_step(cfg: CNNConfig, layouts: List[str], lr: float = 0.01,
 
 
 def loss_fn_fused(params, x_nchw, labels, cfg: CNNConfig, plan: FusedPlan,
-                  impl: str = "pallas", interpret: bool = True):
+                  impl: str = "pallas", interpret: Optional[bool] = None):
     """Differentiable NLL over the FUSED engine: the forward runs the fused
     Pallas kernels and the backward flows through their custom VJPs
     (layout-aware dgrad/wgrad, one-kernel pool+mask backward)."""
@@ -565,7 +565,7 @@ def loss_fn_fused(params, x_nchw, labels, cfg: CNNConfig, plan: FusedPlan,
 
 def make_train_step_fused(cfg: CNNConfig, plan: FusedPlan, lr: float = 0.01,
                           momentum: float = 0.9, impl: str = "pallas",
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """SGD+momentum step over the fused training engine — the layout-aware
     twin of ``make_train_step`` (which autodiffs the unfused XLA forward)."""
     grad_fn = jax.value_and_grad(

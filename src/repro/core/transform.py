@@ -16,7 +16,7 @@ from repro.core.layout import TransformPlan, perm_between, plan_transform
 
 
 def apply_transform(x, src: str, dst: str, *, use_pallas: bool = False,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Re-layout ``x`` from layout ``src`` to ``dst``."""
     if src == dst:
         return x

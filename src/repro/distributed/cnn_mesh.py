@@ -27,7 +27,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import CNNConfig
 
 # the single mesh axis batch rows shard over (matches the LM-side "data"
@@ -52,9 +51,11 @@ def cnn_data_mesh(devices: Optional[int] = None) -> Mesh:
     avail = jax.devices()
     d = len(avail) if devices is None else devices
     if d < 1 or d > len(avail):
-        raise ValueError(
-            f"devices={d} but jax sees {len(avail)} device(s); force host "
-            f"devices with XLA_FLAGS=--xla_force_host_platform_device_count=N")
+        plat = avail[0].platform
+        hint = (" (on the CPU, XLA_FLAGS=--xla_force_host_platform_"
+                "device_count=N forces host devices)" if plat == "cpu" else "")
+        raise ValueError(f"devices={d} but jax sees {len(avail)} {plat} "
+                         f"device(s){hint}")
     return Mesh(np.array(avail[:d]), (BATCH_AXIS,))
 
 
@@ -66,7 +67,7 @@ def replicate_params(params, mesh: Mesh):
 
 def forward_fused_sharded(params, x, shard_cfg: CNNConfig, plan,
                           mesh: Mesh, *, impl: str = "pallas",
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """Data-parallel ``forward_fused``: ``x`` is the GLOBAL padded batch
     ``[shard_cfg.batch * devices, C, H, W]``; each shard executes the fused
     plan on its own ``shard_cfg.batch`` rows with replicated params.
@@ -88,8 +89,8 @@ def forward_fused_sharded(params, x, shard_cfg: CNNConfig, plan,
                              interpret=interpret)
         return y
 
-    f = shard_map(_shard, mesh=mesh, in_specs=(P(), P(BATCH_AXIS)),
-                  out_specs=P(BATCH_AXIS))
+    f = jax.shard_map(_shard, mesh=mesh, in_specs=(P(), P(BATCH_AXIS)),
+                      out_specs=P(BATCH_AXIS), check_vma=False)
     return f(params, x)
 
 

@@ -32,6 +32,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _pad_axis(x, axis, m):
+    p = (-x.shape[axis]) % m
+    if p:
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (0, p)
+        x = jnp.pad(x, pad)
+    return x
+
+
+def _prep_rows(x, h_axis: int, need_rows: int):
+    if x.shape[h_axis] < need_rows:
+        pad = [(0, 0)] * x.ndim
+        pad[h_axis] = (0, need_rows - x.shape[h_axis])
+        x = jnp.pad(x, pad)
+    return x
+
+
 def _spatial_axes(layout: str):
     return (2, 3) if layout == "NCHW" else (1, 2)
 
@@ -75,16 +92,10 @@ def conv_dgrad(g, w, x_hw, stride: int = 1, pad: int = 0, *,
     gd = dilate_grad(g, S, F, g_layout)
     # rotate 180° and swap channel roles: the transposed filter maps Co->Ci
     wt = jnp.transpose(w[:, :, ::-1, ::-1], (1, 0, 2, 3))     # [Ci, Co, F, F]
-    from repro.kernels.conv.ops import (conv_direct_chwn,
-                                        conv_im2col_nchw_fused)
-    if layout == "CHWN":
-        dx = conv_direct_chwn(gd, jnp.transpose(wt, (1, 2, 3, 0)), stride=1,
-                              pad=0, interpret=interpret, src_layout=g_layout,
-                              dst_layout=dst_layout)
-    else:
-        dx = conv_im2col_nchw_fused(gd, wt, stride=1, pad=0,
-                                    interpret=interpret, src_layout=g_layout,
-                                    dst_layout=dst_layout)
+    from repro.kernels.conv.ops import conv_fused
+    dx = conv_fused(gd, wt, stride=1, pad=0, engine=layout,
+                    interpret=interpret, src_layout=g_layout,
+                    dst_layout=dst_layout)
     # dx now covers the PADDED input rows 0..(Ho-1)*S+F-1; the unpadded
     # gradient is the [pad, pad+H) window, zero-filled past the last
     # consumed window when (H + 2*pad - F) % S != 0
@@ -217,7 +228,7 @@ def conv_wgrad(x, g, F: int, S: int = 1, pad: int = 0, *,
     gradient in ``g_layout``.  Pads channels/batch to tile multiples (zero
     contributions) and preps halo rows like the forward wrappers.
     """
-    from repro.kernels.conv.ops import _pad_axis, _prep_rows, conv_blocking
+    from repro.kernels.conv.ops import conv_blocking
     g_layout = g_layout or x_layout
     if x_layout == "NCHW":
         n_axis, ci_axis, h_axis = 0, 1, 2

@@ -1,25 +1,19 @@
-"""Direct convolution Pallas kernel in the CHWN layout (the cuda-convnet
-analogue the paper pairs with CHWN), with a fused epilogue protocol.
+"""Convolution Pallas kernel with a fused epilogue, on flat spatial tiles.
 
-Formulation: for each output-row block, the contraction
-    out[co, ho, wo, n] += x[ci, ho*S+dy, wo*S+dx, n] * w[ci, dy, dx, co]
-is an MXU matmul over ci with N on the 128 lanes — the CHWN layout's
-coalescing dim becomes the MXU minor dim with zero re-layout (the paper's
-§IV.A observation, TPU-native).
+Both of the paper's conv engines run this one kernel (``kernels/flat.py``
+describes the tile form): the direct-CHWN engine with ``nt`` samples
+interleaved on the lanes of a slab, the im2col-MM NCHW engine with one
+sample per slab.  Each filter tap is one MXU matmul of the [Co, Ci] weight
+tap against the whole input slab shifted by the tap — the im2col matrix
+multiply with the patch matrix kept virtual.
 
-Blocking: grid (Ho blocks, Co blocks, N blocks, Ci blocks) with Ci innermost
-(sequential accumulation into a VMEM f32 scratch).  Overlapping input rows
-(stride/halo) are handled by passing the input twice with consecutive
-row-block indices — the halo-stitch trick — so BlockSpec offsets stay
-aligned.
+Grid: (sample groups, Co blocks).  The whole Ci slab and all taps reduce in
+one step into a VMEM f32 scratch, so there is no cross-step accumulator.
 
-Fusion (DESIGN.md §5): on the last Ci step the epilogue runs on the f32
-accumulator while it still lives in VMEM — bias add, ReLU, and (when the
-pool window tiles the output row block) max/avg pooling — and the result is
-written directly in the *consumer's* layout via the out BlockSpec index map
-(``dst_layout``).  The kernel can likewise consume its input in the
-producer's layout (``src_layout``), so a conv absorbs the re-layout on both
-sides and the conv->relu->pool intermediate never touches HBM.
+Fusion (DESIGN.md §5): the epilogue runs on the f32 result while it lives in
+VMEM — bias add, residual add, ReLU and max/avg pooling — and only the final
+(pooled) tensor is written.  ``save_act`` (training) also writes the
+pre-pool activation from the same VMEM slab.
 """
 from __future__ import annotations
 
@@ -32,20 +26,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.shapes import conv_out_hw, pool_out_hw
+from repro.kernels import flat, resolve_interpret
 
 
 @dataclass(frozen=True)
 class Epilogue:
     """What the conv kernel folds into its final VMEM->HBM write.
 
-    ``pool`` is ``(F, S, op)`` with op in {"max", "avg"}; it is only legal
-    when the pool windows tile the conv-output row block (see
-    ``pool_tiles_block``) so no window crosses a grid-block boundary.
-    ``residual`` folds a skip-tensor add onto the VMEM accumulator (after
-    bias, before ReLU — the ResNet epilogue order); the skip arrives through
-    a second layout-folding input BlockSpec, so the standalone add AND its
-    operand re-layout both vanish from HBM traffic (DESIGN.md §11).
+    ``pool`` is ``(F, S, op)`` with op in {"max", "avg"}.  ``residual``
+    folds a skip-tensor add onto the accumulator (after bias, before ReLU —
+    the ResNet epilogue order), so the standalone add and its operand
+    re-layout both vanish from HBM traffic (DESIGN.md §11).
     """
     bias: bool = False
     relu: bool = False
@@ -56,200 +47,113 @@ class Epilogue:
 def pool_tiles_block(bho: int, n_ho: int, pF: int, pS: int) -> bool:
     """True when every pool window lies inside one conv-output row block:
     either one block covers the whole height, or the block height is a
-    multiple of the pool stride and windows don't overlap block seams."""
+    multiple of the pool stride and windows don't overlap block seams.
+    (Row blocking of the planner's cost model; see ``ops.pick_bho``.)"""
     if pF > bho:
         return False
     return n_ho == 1 or (bho % pS == 0 and pF <= pS)
 
 
-def pool_block(y, pF: int, pS: int, op: str):
-    """Pool dims (1, 2) of ``y`` ([C, H, W] or [C, H, W, N]) in VMEM."""
-    bho, wo = y.shape[1], y.shape[2]
-    bpho = pool_out_hw(bho, pF, pS)
-    pwo = pool_out_hw(wo, pF, pS)
-    init = -jnp.inf if op == "max" else 0.0
-    acc = jnp.full(y.shape[:1] + (bpho, pwo) + y.shape[3:], init, jnp.float32)
-    for dy in range(pF):
-        for dx in range(pF):
-            win = y[:, dy:dy + (bpho - 1) * pS + 1:pS,
-                    dx:dx + (pwo - 1) * pS + 1:pS, ...]
-            acc = jnp.maximum(acc, win) if op == "max" else acc + win
-    return acc / (pF * pF) if op == "avg" else acc
+def finish(y_ref, o_ref, *, b_ref, r_ref, z_ref, sel_ref, epi: Epilogue,
+           Ho: int, Wo: int, nt: int, PWo: int):
+    """Shared epilogue tail: compact slab in ``y_ref`` -> bias/residual/
+    ReLU -> optional saved activation -> optional pool -> ``o_ref``."""
+    y = flat.epilogue(y_ref[...],
+                      bias=None if b_ref is None else b_ref[...],
+                      res=None if r_ref is None else r_ref[0],
+                      relu=epi.relu)
+    if z_ref is not None:
+        z_ref[0] = y.astype(z_ref.dtype)
+    if epi.pool is None:
+        o_ref[0] = y.astype(o_ref.dtype)
+        return
+    pF, pS, pop = epi.pool
+    PHo, _ = flat.pool_geometry(Ho, Wo, epi.pool)
+    rl = PWo * nt
+
+    def store(r, v):
+        o_ref[0, :, r * rl:(r + 1) * rl] = v.astype(o_ref.dtype)
+
+    flat.pool_rows(lambda off, size: y[:, off:off + size], store, Wo, nt,
+                   pF, pS, pop, PHo, PWo,
+                   sel=None if sel_ref is None else sel_ref[...])
 
 
-def _conv_kernel(*refs, F, S, bho, Wo, n_ci, epilogue: Epilogue,
-                 src_layout: str, dst_layout: str, res_layout: str = "CHWN",
-                 save_act: bool = False):
-    xa_ref, xb_ref, w_ref = refs[:3]
-    rest = refs[3:]
-    b_ref = r_ref = None
+def _split_refs(refs, epi: Epilogue, has_sel: bool, save_act: bool):
+    rest = list(refs)
+    b_ref = rest.pop(0) if epi.bias else None
+    r_ref = rest.pop(0) if epi.residual else None
+    sel_ref = rest.pop(0) if has_sel else None
+    o_ref = rest.pop(0)
+    z_ref = rest.pop(0) if save_act else None
+    return b_ref, r_ref, sel_ref, o_ref, z_ref, rest
+
+
+def _conv_kernel(x_ref, w_ref, *refs, F, pitch, nt, Ho, Wo, PWo,
+                 epi: Epilogue, has_sel: bool, save_act: bool):
+    b_ref, r_ref, sel_ref, o_ref, z_ref, (acc_ref, y_ref) = _split_refs(
+        refs, epi, has_sel, save_act)
+    flat.conv_taps(lambda start, size: x_ref[0, :, pl.ds(start, size)],
+                   w_ref, acc_ref, F, pitch, nt)
+    flat.compact_rows(acc_ref, y_ref, Ho, 1, pitch, Wo, nt)
+    finish(y_ref, o_ref, b_ref=b_ref, r_ref=r_ref, z_ref=z_ref,
+           sel_ref=sel_ref, epi=epi, Ho=Ho, Wo=Wo, nt=nt, PWo=PWo)
+
+
+def conv_pallas(xf, wt, *, F: int, pitch: int, nt: int, Ho: int, Wo: int,
+                bias=None, res=None, epilogue: Epilogue = Epilogue(),
+                out_dtype=None, save_act: bool = False,
+                interpret: Optional[bool] = None):
+    """Stride-1 conv on flat slabs with a fused epilogue.
+
+    xf: [G, Ci, rows*pitch*nt] (``flat.prep``; pitch = Wo + F - 1; rows
+    from ``flat.conv_lanes``);
+    wt: tap-major [F*F, Co, Ci]; bias: [Co, 1] f32; res: [G, Co, Ho*Wo*nt]
+    (compact).  Returns [G, Co, PHo*PWo*nt] (post-pool when a pool is
+    fused), plus the compact pre-pool activation [G, Co, Ho*Wo*nt] when
+    ``save_act``."""
+    G, Ci, _ = xf.shape
+    T, Co, _ = wt.shape
+    total, rows = flat.conv_lanes(Ho, F, pitch, nt)
+    assert xf.shape[2] >= rows * pitch * nt, (xf.shape, rows, pitch, nt)
+    cot = Co if (Co <= 128 or Co % 128) else 128
+    odt = out_dtype or jnp.result_type(xf.dtype, wt.dtype)
+    PHo, PWo = flat.pool_geometry(Ho, Wo, epilogue.pool)
+    Lo, Lp = Ho * Wo * nt, PHo * PWo * nt
+    in_specs = [pl.BlockSpec((1, Ci, xf.shape[2]), lambda g, c: (g, 0, 0)),
+                pl.BlockSpec((T, cot, Ci), lambda g, c: (0, c, 0))]
+    operands = [xf, wt]
     if epilogue.bias:
-        b_ref, rest = rest[0], rest[1:]
-    if epilogue.residual:
-        r_ref, rest = rest[0], rest[1:]
-    if save_act:
-        o_ref, z_ref, acc_ref = rest
-    else:
-        (o_ref, acc_ref), z_ref = rest, None
-
-    @pl.when(pl.program_id(3) == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    xa = xa_ref[...]                     # [cit, IBH, W, nt] (CHWN blocks)
-    xb = xb_ref[...]
-    if src_layout == "NCHW":             # blocks arrive [nt, cit, IBH, W]
-        xa = jnp.transpose(xa, (1, 2, 3, 0))
-        xb = jnp.transpose(xb, (1, 2, 3, 0))
-    x2 = jnp.concatenate([xa, xb], axis=1)      # rows j*IBH .. j*IBH+2*IBH
-    if jnp.issubdtype(x2.dtype, jnp.integer):
-        # int8 storage (DESIGN.md §9): HBM held 1-byte values; the dequant
-        # happens here in VMEM (the per-channel scale was folded into w by
-        # the caller, so the cast IS the dequant)
-        x2 = x2.astype(jnp.float32)
-    w = w_ref[...]                       # [cit, F, F, cot]
-
-    acc = acc_ref[...]
-    for dy in range(F):
-        for dx in range(F):
-            xs = x2[:, dy:dy + (bho - 1) * S + 1:S,
-                    dx:dx + (Wo - 1) * S + 1:S, :]      # [cit,bho,Wo,nt]
-            acc = acc + jnp.einsum(
-                "chwn,ck->khwn", xs, w[:, dy, dx, :],
-                preferred_element_type=jnp.float32)
-    acc_ref[...] = acc
-
-    @pl.when(pl.program_id(3) == n_ci - 1)
-    def _():
-        y = acc_ref[...]                 # [cot, bho, Wo, nt] f32, in VMEM
-        if epilogue.bias:
-            y = y + b_ref[...].reshape(-1, 1, 1, 1)
-        if epilogue.residual:            # folded skip add, pre-ReLU
-            r = r_ref[...]
-            if res_layout == "NCHW":     # block arrives [nt, cot, bho, Wo]
-                r = jnp.transpose(r, (1, 2, 3, 0))
-            y = y + r.astype(jnp.float32)
-        if epilogue.relu:
-            y = jnp.maximum(y, 0.0)
-        if save_act:                     # training residual: pre-pool, native
-            z_ref[...] = y.astype(z_ref.dtype)
-        if epilogue.pool is not None:
-            pF, pS, pop = epilogue.pool
-            y = pool_block(y, pF, pS, pop)
-        if dst_layout == "NCHW":
-            y = jnp.transpose(y, (3, 0, 1, 2))
-        o_ref[...] = y.astype(o_ref.dtype)
-
-
-def conv_chwn_pallas(x, w, F: int, S: int, *, bho: int = 4, cot: int = 0,
-                     cit: int = 0, nt: int = 128, ibh: int = 0,
-                     bias=None, res=None, res_layout: str = "CHWN",
-                     epilogue: Epilogue = Epilogue(),
-                     src_layout: str = "CHWN", dst_layout: str = "CHWN",
-                     save_act: bool = False, interpret: bool = True):
-    """Direct CHWN conv with fused epilogue and layout-fused I/O.
-
-    x: [Ci, H, W, N] (or [N, Ci, H, W] when ``src_layout == "NCHW"``);
-    w: [Ci, F, F, Co]; bias: [Co, 1] when ``epilogue.bias``; ``res`` (when
-    ``epilogue.residual``) is the skip tensor in ``res_layout``, pre-padded
-    by ops.py to the kernel's Co/row-block/N grid (zero padding — additive
-    identity on rows the caller slices off anyway).
-    Result: [Co, Ho', Wo', N] (or [N, Co, Ho', Wo'] when
-    ``dst_layout == "NCHW"``) where Ho'/Wo' are post-pool when a pool
-    epilogue is fused.  ``save_act`` (training) adds a second output: the
-    pre-pool post-bias/relu activation [Co, Ho, Wo, N] in the kernel's native
-    CHWN layout — the residual the fused backward needs, written from the
-    same VMEM accumulator (no recompute).
-
-    Requirements (ops.py pads): N % nt == 0, Co % cot == 0, Ci % cit == 0,
-    Ho % bho == 0, H >= (row blocks + 1)*IBH, and — with a pool epilogue —
-    ``pool_tiles_block(bho, n_ho, pF, pS)``.  ``ibh`` overrides the input
-    row-block height (default bho*S); legal only when there is a single row
-    block, where it lets the two stitched blocks cover a window span larger
-    than 2*bho*S.
-    """
-    if src_layout == "NCHW":
-        N, Ci, H, W = x.shape
-    else:
-        Ci, H, W, N = x.shape
-    Co = w.shape[-1]
-    Ho = conv_out_hw(H, F, S)          # input arrives pre-padded
-    Wo = conv_out_hw(W, F, S)
-    cot = cot or min(Co, 128)
-    cit = cit or min(Ci, 32)
-    IBH = ibh or bho * S
-    n_ci = Ci // cit
-    if IBH == bho * S:
-        n_ho = Ho // bho          # may exceed the true count (halo padding);
-    else:                         # ops.py slices the spurious rows off
-        n_ho = 1                  # ibh override: single row block by contract
-        assert 2 * IBH >= (bho - 1) * S + F, (IBH, bho, S, F)
-
-    obho, OWo = bho, Wo
-    if epilogue.pool is not None:
-        pF, pS, _ = epilogue.pool
-        assert pool_tiles_block(bho, n_ho, pF, pS), (bho, n_ho, pF, pS)
-        obho = pool_out_hw(bho, pF, pS)
-        OWo = pool_out_hw(Wo, pF, pS)
-    OHo = n_ho * obho
-
-    if src_layout == "NCHW":
-        in_specs = [
-            pl.BlockSpec((nt, cit, IBH, W), lambda h, c, n, k: (n, k, h, 0)),
-            pl.BlockSpec((nt, cit, IBH, W),
-                         lambda h, c, n, k: (n, k, h + 1, 0)),
-        ]
-    else:
-        in_specs = [
-            pl.BlockSpec((cit, IBH, W, nt), lambda h, c, n, k: (k, h, 0, n)),
-            pl.BlockSpec((cit, IBH, W, nt),
-                         lambda h, c, n, k: (k, h + 1, 0, n)),
-        ]
-    in_specs.append(pl.BlockSpec((cit, F, F, cot),
-                                 lambda h, c, n, k: (k, 0, 0, c)))
-    operands = [x, x, w]
-    if epilogue.bias:
-        assert bias is not None
-        in_specs.append(pl.BlockSpec((cot, 1), lambda h, c, n, k: (c, 0)))
+        in_specs.append(pl.BlockSpec((cot, 1), lambda g, c: (c, 0)))
         operands.append(bias)
     if epilogue.residual:
-        assert res is not None
-        if res_layout == "NCHW":
-            in_specs.append(pl.BlockSpec((nt, cot, bho, Wo),
-                                         lambda h, c, n, k: (n, c, h, 0)))
-        else:
-            in_specs.append(pl.BlockSpec((cot, bho, Wo, nt),
-                                         lambda h, c, n, k: (c, h, 0, n)))
+        in_specs.append(pl.BlockSpec((1, cot, Lo), lambda g, c: (g, c, 0)))
         operands.append(res)
-
-    # int8 x emits the float compute dtype (= w's dtype: the storage cast
-    # back to int8, when planned, is the NEXT boundary's quantize)
-    odt = jnp.result_type(x.dtype, w.dtype)
-    if dst_layout == "NCHW":
-        out_shape = jax.ShapeDtypeStruct((N, Co, OHo, OWo), odt)
-        out_specs = pl.BlockSpec((nt, cot, obho, OWo),
-                                 lambda h, c, n, k: (n, c, h, 0))
-    else:
-        out_shape = jax.ShapeDtypeStruct((Co, OHo, OWo, N), odt)
-        out_specs = pl.BlockSpec((cot, obho, OWo, nt),
-                                 lambda h, c, n, k: (c, h, 0, n))
+    has_sel = epilogue.pool is not None and epilogue.pool[1] > 1
+    if has_sel:
+        sel = flat.selection(epilogue.pool[1], PWo, nt)
+        in_specs.append(pl.BlockSpec(sel.shape, lambda g, c: (0, 0)))
+        operands.append(sel)
+    out_shape = [jax.ShapeDtypeStruct((G, Co, Lp), odt)]
+    out_specs = [pl.BlockSpec((1, cot, Lp), lambda g, c: (g, c, 0))]
     if save_act:
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((Co, n_ho * bho, Wo, N), odt)]
-        out_specs = [out_specs,
-                     pl.BlockSpec((cot, bho, Wo, nt),
-                                  lambda h, c, n, k: (c, h, 0, n))]
-
-    kern = functools.partial(_conv_kernel, F=F, S=S, bho=bho, Wo=Wo,
-                             n_ci=n_ci, epilogue=epilogue,
-                             src_layout=src_layout, dst_layout=dst_layout,
-                             res_layout=res_layout, save_act=save_act)
-    return pl.pallas_call(
+        out_shape.append(jax.ShapeDtypeStruct((G, Co, Lo), odt))
+        out_specs.append(pl.BlockSpec((1, cot, Lo), lambda g, c: (g, c, 0)))
+    isz = jnp.dtype(xf.dtype).itemsize
+    nbytes = (Ci * xf.shape[2] * isz + T * cot * Ci * wt.dtype.itemsize
+              + cot * total * 4 + 3 * cot * Lo * 4 + cot * Lp * 4)
+    kern = functools.partial(_conv_kernel, F=F, pitch=pitch, nt=nt, Ho=Ho,
+                             Wo=Wo, PWo=PWo, epi=epilogue, has_sel=has_sel,
+                             save_act=save_act)
+    out = pl.pallas_call(
         kern,
         out_shape=out_shape,
-        grid=(n_ho, Co // cot, N // nt, n_ci),
+        grid=(G, Co // cot),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=[pltpu.VMEM((cot, bho, Wo, nt), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((cot, total), jnp.float32),
+                        pltpu.VMEM((cot, Lo), jnp.float32)],
+        compiler_params=flat.compiler_params(2, nbytes),
+        interpret=resolve_interpret(interpret),
     )(*operands)
+    return out if save_act else out[0]

@@ -2,18 +2,23 @@
 
 These are the paper's three convolution implementations, each bound to its
 preferred layout (§II.B, §IV.A):
-  * direct  (CHWN)  — cuda-convnet analogue, Pallas kernel;
+  * direct  (CHWN)  — cuda-convnet analogue;
   * im2col + MXU matmul (NCHW) — Caffe/cuDNN analogue.  Two forms: the
-    native all-Pallas kernel (``conv_im2col_nchw_fused``, the default engine)
-    and the seed's XLA-expansion + Pallas-matmul baseline
-    (``conv_im2col_nchw``, kept for comparison);
+    fused Pallas engine and the seed's XLA-expansion + Pallas-matmul
+    baseline (``conv_im2col_nchw``, kept for comparison);
   * FFT (NCHW) — cuDNN-FFT analogue (jnp.fft; XLA).
 
-The two Pallas wrappers speak the fused-epilogue protocol (DESIGN.md §5):
-``bias``/``relu``/``pool`` fold elementwise and pooling work into the conv's
-output write, and ``src_layout``/``dst_layout`` make the kernel consume and
-produce tensors in the neighbouring layers' layouts so no standalone
-re-layout pass is needed.
+The CHWN and NCHW Pallas engines are one entry point, ``conv_fused(...,
+engine=)`` (``conv_direct_chwn``/``conv_im2col_nchw_fused`` are its
+engine-bound spellings), and run the one kernel of ``kernels/conv/conv.py``:
+CHWN interleaves up to ``flat.CHWN_NT`` samples per slab, NCHW runs one.
+It speaks the fused-epilogue protocol (DESIGN.md §5): ``bias``/``relu``/
+``pool`` fold elementwise and pooling work into the conv's output write,
+and ``src_layout``/``dst_layout`` fold the neighbouring layers' layouts
+into the one XLA copy that feeds the kernel its flat slabs
+(``kernels/flat.py``) and the one that stores its result, so no standalone
+re-layout pass is needed.  ``conv_stack`` is the same for conv->conv
+stacks.
 """
 from __future__ import annotations
 
@@ -22,24 +27,14 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.kernels.conv.conv import (Epilogue, conv_chwn_pallas,
-                                     pool_tiles_block)
-from repro.kernels.conv.im2col_mm import conv_nchw_pallas
+from repro.kernels import flat, resolve_interpret
+from repro.kernels.conv.conv import Epilogue, conv_pallas, pool_tiles_block
 from repro.kernels.conv.ref import im2col_nchw
-from repro.kernels.conv.stack import (conv_stack_chwn_pallas,
-                                      conv_stack_nchw_pallas)
+from repro.kernels.conv.stack import conv_stack_pallas, stack_lanes
 from repro.kernels.matmul.ops import matmul
 from repro.shapes import conv_out_hw
-
-
-def _pad_axis(x, axis, m):
-    p = (-x.shape[axis]) % m
-    if p:
-        pad = [(0, 0)] * x.ndim
-        pad[axis] = (0, p)
-        x = jnp.pad(x, pad)
-    return x
 
 
 def pick_bho(Ho: int, F: int, S: int,
@@ -59,7 +54,7 @@ def pick_bho(Ho: int, F: int, S: int,
 
 def conv_blocking(Ho: int, F: int, S: int,
                   pool: Optional[Tuple[int, int, str]] = None):
-    """Row blocking shared by the conv forward engines and wgrad:
+    """Row blocking of the planner's conv cost model and of wgrad:
     (output row block, input row block, row-block count).  The halo trick
     needs the two stitched input blocks to cover one window span, so when
     the whole-height fallback gives bho below that bound the input block is
@@ -87,113 +82,71 @@ def stack_blocking(Ho2: int, F1: int, S1: int, F2: int, S2: int,
     return bho, IBH, n_ho, mho
 
 
-def _prep_rows(x, h_axis: int, need_rows: int):
-    if x.shape[h_axis] < need_rows:
-        pad = [(0, 0)] * x.ndim
-        pad[h_axis] = (0, need_rows - x.shape[h_axis])
-        x = jnp.pad(x, pad)
-    return x
-
-
-def _pad_channels(x, w, bias, ci_axes, co_axes, cit: int, cot: int):
-    """Zero-pad Ci/Co to tile multiples: zero input channels contribute
-    nothing and padded output channels are sliced off by the caller.
-    ``ci_axes`` = (x axis, w axis) of Ci; ``co_axes`` = (w axis,) of Co."""
-    x = _pad_axis(x, ci_axes[0], cit)
-    w = _pad_axis(_pad_axis(w, ci_axes[1], cit), co_axes[0], cot)
-    if bias is not None:
-        bias = _pad_axis(bias, 0, cot)
-    return x, w, bias
-
-
-def _kernel_rows(H_padded: int, F: int, S: int, bho: int, IBH: int) -> int:
-    """Pre-pool output rows the kernel's grid will actually write: the
-    engine re-derives its row-block count from the halo-padded input (one
-    block when the ibh override is active), so grid-shaped side operands
-    (the folded residual) must be padded to this height, not the true Ho."""
-    if IBH != bho * S:
-        return bho                      # ibh override: single row block
-    return (conv_out_hw(H_padded, F, S) // bho) * bho
-
-
-def _prep_res(res, res_layout: str, cot: int, nt: int, grid_rows: int):
-    """Zero-pad the skip operand of a folded residual add to the kernel's
-    grid: channels to the ``cot`` multiple, rows to the halo-padded
-    row-block grid (which can exceed the true output height when F <= S),
-    and N to the ``nt`` multiple when the engine blocks N.  Zeros are the
-    additive identity and the spurious rows land in output rows the caller
-    slices off, so padding never perturbs the result."""
-    c_ax, h_ax, n_ax = (1, 2, 0) if res_layout == "NCHW" else (0, 1, 3)
-    res = _pad_axis(res, c_ax, cot)
-    if nt:
-        res = _pad_axis(res, n_ax, nt)
-    return _prep_rows(res, h_ax, grid_rows)
-
-
-def _conv_chwn_core(x, w, bias, res, stride, pad, nt, interpret, relu, pool,
-                    src_layout, dst_layout, res_layout: str = "CHWN",
-                    save_act: bool = False):
-    F = w.shape[1]
-    if src_layout == "NCHW":
-        N = x.shape[0]
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad)))
-        H, W = x.shape[2], x.shape[3]
-        n_axis, h_axis = 0, 2
-    else:
-        N = x.shape[3]
-        if pad:
-            x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-        H, W = x.shape[1], x.shape[2]
-        n_axis, h_axis = 3, 1
-    Ho = conv_out_hw(H, F, stride)     # H/W already padded above
-    Wo = conv_out_hw(W, F, stride)
-    Co = w.shape[-1]
-    cit = min(w.shape[0], 32)
-    cot = min(Co, 128)
-    x, w, bias = _pad_channels(x, w, bias,
-                               ci_axes=(1 if src_layout == "NCHW" else 0, 0),
-                               co_axes=(3,), cit=cit, cot=cot)
-    bho, IBH, n_ho = conv_blocking(Ho, F, stride, pool)
-    nt = min(nt, max(N, 1))
-    xn = _pad_axis(x, n_axis, nt)
-    # halo block (j+1) must exist: pad rows by one extra input block
-    xn = _prep_rows(xn, h_axis, (n_ho + 1) * IBH)
+def _conv_core(x, w, bias, res, stride, pad, nt, interpret, relu, pool,
+               src_layout, dst_layout, res_layout, engine: str,
+               save_act: bool = False):
+    """Forward of ``conv_fused``: flat-slab prep (pad, stride removed by
+    space-to-depth, re-layout from ``src_layout``), one fused kernel,
+    re-layout to ``dst_layout``.  ``w`` is canonical [Co,Ci,F,F].  Returns
+    (y, z) with z the pre-pool activation in the engine's layout when
+    ``save_act``."""
+    xn = flat.to_nchw(x, src_layout)
+    N, _, H, W = xn.shape
+    Co, _, F, _ = w.shape
+    Ho = conv_out_hw(H + 2 * pad, F, stride)
+    Wo = conv_out_hw(W + 2 * pad, F, stride)
+    Fq = -(-F // stride)
+    pitch = Wo + Fq - 1
+    nt = flat.group_tile(N, engine, nt, (Ho + Fq) * pitch)
+    cm = flat.sublane_multiple(x.dtype)
+    cdt = w.dtype if x.dtype == jnp.int8 else jnp.result_type(x, w)
+    _, rows = flat.conv_lanes(Ho, Fq, pitch, nt)
+    xf = flat.prep(xn, "NCHW", pad=pad, stride=stride, rows=rows,
+                   cols=pitch, nt=nt, cmult=cm)
+    wt = flat.s2d_weights(w, stride, cm).astype(cdt)
     if res is not None:
-        res = _prep_res(res, res_layout, cot, nt,
-                        _kernel_rows(xn.shape[h_axis], F, stride, bho, IBH))
+        res = flat.prep(res, res_layout, pad=0, stride=1, rows=Ho, cols=Wo,
+                        nt=nt, cmult=1)
+    b2 = bias.reshape(-1, 1).astype(jnp.float32) if bias is not None else None
     ep = Epilogue(bias=bias is not None, relu=relu, pool=pool,
                   residual=res is not None)
-    b2 = bias.reshape(-1, 1).astype(jnp.float32) if bias is not None else None
-    y = conv_chwn_pallas(xn, w, F, stride, bho=bho, cit=cit, cot=cot, nt=nt,
-                         ibh=IBH, bias=b2, res=res, res_layout=res_layout,
-                         epilogue=ep, src_layout=src_layout,
-                         dst_layout=dst_layout, save_act=save_act,
-                         interpret=interpret)
-    # the engine recomputes its row count from the halo-padded input, which
-    # gains spurious row blocks when F <= S: slice back to the true height
-    obho = bho if pool is None else (bho - pool[0]) // pool[1] + 1
-    OHo = n_ho * obho
-    if save_act:
-        y, z = y
-        z = z[:Co, :n_ho * bho, :, :N]   # pre-pool act, native CHWN
-    else:
-        z = None
-    y = (y[:N, :Co, :OHo] if dst_layout == "NCHW"
-         else y[:Co, :OHo, :, :N])
+    out = conv_pallas(xf, wt, F=Fq, pitch=pitch, nt=nt, Ho=Ho, Wo=Wo,
+                      bias=b2, res=res, epilogue=ep, out_dtype=cdt,
+                      save_act=save_act,
+                      interpret=interpret)
+    y, z = out if save_act else (out, None)
+    PHo, PWo = flat.pool_geometry(Ho, Wo, pool)
+    y = flat.unprep(y, N, Co, PHo, PWo, nt, dst_layout)
+    if z is not None:
+        z = flat.unprep(z, N, Co, Ho, Wo, nt, engine)
     return y, z
 
 
-def _conv_bwd(prims, g, *, layout, stride, pad, interpret, relu, pool,
-              src_layout, dst_layout, res_layout="CHWN"):
-    """Shared VJP body for both conv engines.
+@partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 14)))
+def _conv_vjp(x, w, bias, res, stride, pad, nt, interpret, relu, pool,
+              src_layout, dst_layout, res_layout, engine):
+    return _conv_core(x, w, bias, res, stride, pad, nt, interpret, relu, pool,
+                      src_layout, dst_layout, res_layout, engine)[0]
 
-    ``x``/``w``/``bias`` enter in the engine's native forms; ``g`` arrives in
-    ``dst_layout``.  The reversed re-layout chain folds into kernel I/O maps:
-    pool backward consumes ``g`` in ``dst_layout`` directly and the dgrad
-    engine writes dx straight in ``src_layout``.  Residual ``z`` (pre-pool
-    post-relu activation, compute layout) was stashed by the forward kernel's
-    ``save_act`` epilogue — no recompute pass.
+
+def _conv_fwd(x, w, bias, res, stride, pad, nt, interpret, relu, pool,
+              src_layout, dst_layout, res_layout, engine):
+    y, z = _conv_core(x, w, bias, res, stride, pad, nt, interpret, relu, pool,
+                      src_layout, dst_layout, res_layout, engine,
+                      save_act=pool is not None)
+    return y, (x, w, bias, res, y, z)
+
+
+def _conv_bwd(stride, pad, nt, interpret, relu, pool, src_layout, dst_layout,
+              res_layout, engine, prims, g):
+    """VJP of ``conv_fused``.
+
+    ``x``/``bias`` enter as the forward saw them, ``w`` canonical; ``g``
+    arrives in ``dst_layout``.  The reversed re-layout chain folds into
+    kernel I/O maps: pool backward consumes ``g`` in ``dst_layout`` directly
+    and the dgrad engine writes dx straight in ``src_layout``.  Residual
+    ``z`` (pre-pool post-relu activation, engine layout) was stashed by the
+    forward kernel's ``save_act`` epilogue — no recompute pass.
 
     A folded skip add (``skip`` is not None) fans the gradient out: the
     post-relu-mask/pool-backward gradient IS d(skip) up to a re-layout,
@@ -201,13 +154,9 @@ def _conv_bwd(prims, g, *, layout, stride, pad, interpret, relu, pool,
     """
     from repro.kernels.conv.backward import bias_grad, conv_dgrad, conv_wgrad
     from repro.kernels.pool.backward import pool_backward
+    interpret = resolve_interpret(interpret)
     x, w, bias, skip, y, z = prims
-    if layout == "CHWN":
-        w_oihw = jnp.transpose(w, (3, 0, 1, 2))
-        F = w.shape[1]
-    else:
-        w_oihw = w
-        F = w.shape[2]
+    F = w.shape[2]
     if src_layout == "NCHW":
         x_hw = (x.shape[2], x.shape[3])
     else:
@@ -215,20 +164,18 @@ def _conv_bwd(prims, g, *, layout, stride, pad, interpret, relu, pool,
     if pool is not None:
         # one kernel: route g through the max-mask/avg-scatter AND apply the
         # relu mask (z is in VMEM for the mask anyway)
-        ga = pool_backward(z, g, pool[0], pool[1], pool[2], layout=layout,
+        ga = pool_backward(z, g, pool[0], pool[1], pool[2], layout=engine,
                            g_layout=dst_layout, relu_mask=relu,
                            interpret=interpret)
-        g_lay = layout
+        g_lay = engine
     else:
         ga = g * (y > 0).astype(g.dtype) if relu else g
         g_lay = dst_layout
-    dx = conv_dgrad(ga, w_oihw, x_hw, stride, pad, layout=layout,
+    dx = conv_dgrad(ga, w, x_hw, stride, pad, layout=engine,
                     g_layout=g_lay, dst_layout=src_layout,
                     interpret=interpret)
-    dw_oihw = conv_wgrad(x, ga, F, stride, pad, x_layout=src_layout,
-                         g_layout=g_lay, interpret=interpret)
-    dw = (jnp.transpose(dw_oihw, (1, 2, 3, 0)) if layout == "CHWN"
-          else dw_oihw)
+    dw = conv_wgrad(x, ga, F, stride, pad, x_layout=src_layout,
+                    g_layout=g_lay, interpret=interpret)
     db = None
     if bias is not None:
         db = bias_grad(ga, g_lay).astype(bias.dtype)
@@ -239,141 +186,48 @@ def _conv_bwd(prims, g, *, layout, stride, pad, interpret, relu, pool,
     return dx.astype(x.dtype), dw.astype(w.dtype), db, dskip
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
-def _conv_chwn_vjp(x, w, bias, res, stride, pad, nt, interpret, relu, pool,
-                   src_layout, dst_layout, res_layout):
-    return _conv_chwn_core(x, w, bias, res, stride, pad, nt, interpret, relu,
-                           pool, src_layout, dst_layout, res_layout)[0]
+_conv_vjp.defvjp(_conv_fwd, _conv_bwd)
 
 
-def _conv_chwn_fwd(x, w, bias, res, stride, pad, nt, interpret, relu, pool,
-                   src_layout, dst_layout, res_layout):
-    y, z = _conv_chwn_core(x, w, bias, res, stride, pad, nt, interpret, relu,
-                           pool, src_layout, dst_layout, res_layout,
-                           save_act=pool is not None)
-    return y, (x, w, bias, res, y, z)
+@partial(jax.jit, static_argnames=("stride", "pad", "engine", "nt",
+                                   "interpret", "relu", "pool", "src_layout",
+                                   "dst_layout", "res_layout"))
+def conv_fused(x, w, stride: int = 1, pad: int = 0, *, engine: str,
+               nt: int = flat.CHWN_NT, interpret: Optional[bool] = None,
+               bias=None, relu: bool = False,
+               pool: Optional[Tuple[int, int, str]] = None,
+               res=None, res_layout: Optional[str] = None,
+               src_layout: Optional[str] = None,
+               dst_layout: Optional[str] = None):
+    """Fused conv node on the ``engine`` ("CHWN" or "NCHW") Pallas path: x
+    in ``src_layout``, w canonical [Co,Ci,F,F] -> output in ``dst_layout``,
+    with optional fused bias/residual-add/ReLU/pool epilogue (``res`` is the
+    skip tensor, stored in ``res_layout``; the three layouts default to
+    ``engine``).  The engines run one kernel and differ in samples per slab:
+    CHWN interleaves up to ``nt`` (``flat.group_tile``), NCHW runs one.
+    Differentiable: a custom VJP routes the backward pass through the
+    layout-aware dgrad/wgrad Pallas engines and fans the gradient out to the
+    skip branch when a residual is folded."""
+    return _conv_vjp(x, w, bias, res, stride, pad, nt, interpret, relu, pool,
+                     src_layout or engine, dst_layout or engine,
+                     res_layout or engine, engine)
 
 
-def _conv_chwn_bwd(stride, pad, nt, interpret, relu, pool, src_layout,
-                   dst_layout, res_layout, prims, g):
-    return _conv_bwd(prims, g, layout="CHWN", stride=stride, pad=pad,
-                     interpret=interpret, relu=relu, pool=pool,
-                     src_layout=src_layout, dst_layout=dst_layout,
-                     res_layout=res_layout)
+def conv_direct_chwn(x, w, stride: int = 1, pad: int = 0,
+                     nt: int = flat.CHWN_NT,
+                     interpret: Optional[bool] = None, **kw):
+    """``conv_fused`` on the CHWN engine with its native weights: x
+    [Ci,H,W,N], w [Ci,F,F,Co] -> [Co,Ho',Wo',N]."""
+    return conv_fused(x, jnp.transpose(w, (3, 0, 1, 2)), stride, pad,
+                      engine="CHWN", nt=nt, interpret=interpret, **kw)
 
 
-_conv_chwn_vjp.defvjp(_conv_chwn_fwd, _conv_chwn_bwd)
-
-
-@partial(jax.jit, static_argnames=("stride", "pad", "interpret", "nt", "relu",
-                                   "pool", "src_layout", "dst_layout",
-                                   "res_layout"))
-def conv_direct_chwn(x, w, stride: int = 1, pad: int = 0, nt: int = 128,
-                     interpret: bool = True, *, bias=None, relu: bool = False,
-                     pool: Optional[Tuple[int, int, str]] = None,
-                     res=None, res_layout: str = "CHWN",
-                     src_layout: str = "CHWN", dst_layout: str = "CHWN"):
-    """Direct conv, CHWN engine: x [Ci,H,W,N] (or [N,Ci,H,W] for src NCHW),
-    w [Ci,F,F,Co] -> [Co,Ho',Wo',N] (or NCHW for dst NCHW), with optional
-    fused bias/residual-add/ReLU/pool epilogue (``res`` is the skip tensor,
-    stored in ``res_layout``).  Differentiable: a custom VJP routes the
-    backward pass through the layout-aware dgrad/wgrad Pallas engines and
-    fans the gradient out to the skip branch when a residual is folded."""
-    return _conv_chwn_vjp(x, w, bias, res, stride, pad, nt, interpret, relu,
-                          pool, src_layout, dst_layout, res_layout)
-
-
-def _conv_nchw_core(x, w, bias, res, stride, pad, interpret, relu, pool,
-                    src_layout, dst_layout, res_layout: str = "NCHW",
-                    save_act: bool = False):
-    F = w.shape[2]
-    if src_layout == "CHWN":
-        N = x.shape[3]
-        if pad:
-            x = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-        H, W = x.shape[1], x.shape[2]
-        h_axis = 1
-    else:
-        N = x.shape[0]
-        if pad:
-            x = jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad)))
-        H, W = x.shape[2], x.shape[3]
-        h_axis = 2
-    Ho = conv_out_hw(H, F, stride)     # H already padded above
-    Co = w.shape[0]
-    cit = min(w.shape[1], 32)
-    cot = min(Co, 128)
-    x, w, bias = _pad_channels(x, w, bias,
-                               ci_axes=(0 if src_layout == "CHWN" else 1, 1),
-                               co_axes=(0,), cit=cit, cot=cot)
-    bho, IBH, n_ho = conv_blocking(Ho, F, stride, pool)
-    xn = _prep_rows(x, h_axis, (n_ho + 1) * IBH)
-    if res is not None:
-        res = _prep_res(res, res_layout, cot, 0,
-                        _kernel_rows(xn.shape[h_axis], F, stride, bho, IBH))
-    ep = Epilogue(bias=bias is not None, relu=relu, pool=pool,
-                  residual=res is not None)
-    b2 = bias.reshape(-1, 1).astype(jnp.float32) if bias is not None else None
-    y = conv_nchw_pallas(xn, w, F, stride, bho=bho, cit=cit, cot=cot, ibh=IBH,
-                         bias=b2, res=res, res_layout=res_layout,
-                         epilogue=ep, src_layout=src_layout,
-                         dst_layout=dst_layout, save_act=save_act,
-                         interpret=interpret)
-    # slice off spurious row blocks from the halo padding (F <= S cases)
-    obho = bho if pool is None else (bho - pool[0]) // pool[1] + 1
-    OHo = n_ho * obho
-    if save_act:
-        y, z = y
-        z = z[:, :Co, :n_ho * bho]       # pre-pool act, native NCHW
-    else:
-        z = None
-    y = y[:Co, :OHo] if dst_layout == "CHWN" else y[:, :Co, :OHo]
-    return y, z
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
-def _conv_nchw_vjp(x, w, bias, res, stride, pad, interpret, relu, pool,
-                   src_layout, dst_layout, res_layout):
-    return _conv_nchw_core(x, w, bias, res, stride, pad, interpret, relu,
-                           pool, src_layout, dst_layout, res_layout)[0]
-
-
-def _conv_nchw_fwd(x, w, bias, res, stride, pad, interpret, relu, pool,
-                   src_layout, dst_layout, res_layout):
-    y, z = _conv_nchw_core(x, w, bias, res, stride, pad, interpret, relu,
-                           pool, src_layout, dst_layout, res_layout,
-                           save_act=pool is not None)
-    return y, (x, w, bias, res, y, z)
-
-
-def _conv_nchw_bwd(stride, pad, interpret, relu, pool, src_layout,
-                   dst_layout, res_layout, prims, g):
-    return _conv_bwd(prims, g, layout="NCHW", stride=stride, pad=pad,
-                     interpret=interpret, relu=relu, pool=pool,
-                     src_layout=src_layout, dst_layout=dst_layout,
-                     res_layout=res_layout)
-
-
-_conv_nchw_vjp.defvjp(_conv_nchw_fwd, _conv_nchw_bwd)
-
-
-@partial(jax.jit, static_argnames=("stride", "pad", "interpret", "relu",
-                                   "pool", "src_layout", "dst_layout",
-                                   "res_layout"))
 def conv_im2col_nchw_fused(x, w, stride: int = 1, pad: int = 0,
-                           interpret: bool = True, *, bias=None,
-                           relu: bool = False,
-                           pool: Optional[Tuple[int, int, str]] = None,
-                           res=None, res_layout: str = "NCHW",
-                           src_layout: str = "NCHW",
-                           dst_layout: str = "NCHW"):
-    """Native im2col-MM conv, NCHW engine: x [N,Ci,H,W] (or [Ci,H,W,N] for
-    src CHWN), w canonical [Co,Ci,F,F] -> [N,Co,Ho',Wo'] (or CHWN for dst
-    CHWN), with optional fused bias/residual-add/ReLU/pool epilogue (``res``
-    is the skip tensor, stored in ``res_layout``).  Differentiable via the
-    same custom-VJP machinery as the CHWN engine."""
-    return _conv_nchw_vjp(x, w, bias, res, stride, pad, interpret, relu,
-                          pool, src_layout, dst_layout, res_layout)
+                           interpret: Optional[bool] = None, **kw):
+    """``conv_fused`` on the NCHW engine: x [N,Ci,H,W], w [Co,Ci,F,F] ->
+    [N,Co,Ho',Wo']."""
+    return conv_fused(x, w, stride, pad, engine="NCHW", interpret=interpret,
+                      **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -384,85 +238,83 @@ def conv_im2col_nchw_fused(x, w, stride: int = 1, pad: int = 0,
 def _stack_core(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2, nt,
                 interpret, relu1, relu2, pool, src_layout, dst_layout,
                 res_layout, engine):
-    """Shared stack wrapper: pads (conv1 padding + conv2 padding pulled to
-    the input at stride1 scale + halo block), derives the composite blocking,
-    dispatches to the engine kernel, and slices the spurious halo rows."""
-    if engine == "CHWN":
-        F1, F2 = w1.shape[1], w2.shape[1]
-        Cm, Co = w1.shape[-1], w2.shape[-1]
-    else:
-        F1, F2 = w1.shape[2], w2.shape[2]
-        Cm, Co = w1.shape[0], w2.shape[0]
-    P = pad1 + stride1 * pad2        # conv2 padding folded to the input
-    if src_layout == "NCHW":
-        N = x.shape[0]
-        H0, W0 = x.shape[2], x.shape[3]
-        if P:
-            x = jnp.pad(x, ((0, 0), (0, 0), (P, P), (P, P)))
-        h_axis, n_axis = 2, 0
-    else:
-        N = x.shape[3]
-        H0, W0 = x.shape[1], x.shape[2]
-        if P:
-            x = jnp.pad(x, ((0, 0), (P, P), (P, P), (0, 0)))
-        h_axis, n_axis = 1, 3
+    """Forward of ``conv_stack``: pads (conv1 padding + conv2 padding pulled
+    to the input at stride1 scale), removes conv1's stride by
+    space-to-depth, builds the mid validity mask, and dispatches the one
+    stack kernel.  Weights are canonical [Co,Ci,F,F]."""
+    Cm, _, F1, _ = w1.shape
+    Co, _, F2, _ = w2.shape
+    xn = flat.to_nchw(x, src_layout)
+    N, _, H0, W0 = xn.shape
     Ho1 = conv_out_hw(H0 + 2 * pad1, F1, stride1)
     Wo1 = conv_out_hw(W0 + 2 * pad1, F1, stride1)
     Ho2 = conv_out_hw(Ho1 + 2 * pad2, F2, stride2)
-    bho, IBH, n_ho, mho = stack_blocking(Ho2, F1, stride1, F2, stride2, pool)
-    S_eff, F_eff = stride1 * stride2, (F2 - 1) * stride1 + F1
-    xn = x
-    if engine == "CHWN":
-        nt = min(nt, max(N, 1))
-        xn = _pad_axis(xn, n_axis, nt)
-    xn = _prep_rows(xn, h_axis, (n_ho + 1) * IBH)
+    Wo2 = conv_out_hw(Wo1 + 2 * pad2, F2, stride2)
+    F1q = -(-F1 // stride1)
+    # mid columns conv2's stride-1 wide pass reads, plus conv1's taps
+    pitch = (Wo2 - 1) * stride2 + F2 + F1q - 1
+    nt = flat.group_tile(N, engine, nt,
+                         ((Ho2 - 1) * stride2 + F2 + F1q) * pitch)
+    cm = flat.sublane_multiple(x.dtype)
+    cdt = w1.dtype if x.dtype == jnp.int8 else jnp.result_type(x, w1)
+    Lm, _, rows = stack_lanes(Ho2, stride2, F1q, F2, pitch, nt)
+    xf = flat.prep(xn, "NCHW", pad=pad1 + stride1 * pad2, stride=stride1,
+                   rows=rows, cols=pitch, nt=nt, cmult=cm)
+    Cmp = flat.ceil_to(Cm, 8)            # zero mid channels stay zero
+    w1t = flat.s2d_weights(jnp.pad(w1, ((0, Cmp - Cm),) + ((0, 0),) * 3),
+                           stride1, cm).astype(cdt)
+    w2t = flat.s2d_weights(jnp.pad(w2, ((0, 0), (0, Cmp - Cm), (0, 0),
+                                        (0, 0))), 1, 1).astype(cdt)
+    b1v = jnp.zeros((Cm,), jnp.float32) if b1 is None else b1
+    b1v = jnp.pad(b1v.astype(jnp.float32), (0, Cmp - Cm)).reshape(-1, 1)
+    b2v = b2.reshape(-1, 1).astype(jnp.float32) if b2 is not None else None
+    lane = np.arange(Lm)
+    r, c = lane // (pitch * nt), (lane // nt) % pitch
+    keep = (r >= pad2) & (r < pad2 + Ho1) & (c >= pad2) & (c < pad2 + Wo1)
+    mask = jnp.asarray(keep.astype(np.float32)[None])
     if res is not None:
-        res = _prep_res(res, res_layout, 1, nt if engine == "CHWN" else 0,
-                        _kernel_rows(xn.shape[h_axis], F_eff, S_eff,
-                                     bho, IBH))
+        res = flat.prep(res, res_layout, pad=0, stride=1, rows=Ho2, cols=Wo2,
+                        nt=nt, cmult=1)
     ep = Epilogue(bias=b2 is not None, relu=relu2, pool=pool,
                   residual=res is not None)
-    b1v = (b1 if b1 is not None else jnp.zeros((Cm,)))
-    b1v = b1v.reshape(-1, 1).astype(jnp.float32)
-    b2v = b2.reshape(-1, 1).astype(jnp.float32) if b2 is not None else None
-    valid = ((pad2, pad2 + Ho1), (pad2, pad2 + Wo1))
-    if engine == "CHWN":
-        y = conv_stack_chwn_pallas(
-            xn, w1, b1v, w2, F1, stride1, F2, stride2, bho=bho, ibh=IBH,
-            mho=mho, nt=nt, valid_rows=valid[0], valid_cols=valid[1],
-            relu1=relu1, bias2=b2v, res=res, res_layout=res_layout,
-            epilogue=ep, src_layout=src_layout, dst_layout=dst_layout,
-            interpret=interpret)
-    else:
-        y = conv_stack_nchw_pallas(
-            xn, w1, b1v, w2, F1, stride1, F2, stride2, bho=bho, ibh=IBH,
-            mho=mho, valid_rows=valid[0], valid_cols=valid[1],
-            relu1=relu1, bias2=b2v, res=res, res_layout=res_layout,
-            epilogue=ep, src_layout=src_layout, dst_layout=dst_layout,
-            interpret=interpret)
-    obho = bho if pool is None else (bho - pool[0]) // pool[1] + 1
-    OHo = (Ho2 // bho) * obho
-    return (y[:N, :Co, :OHo] if dst_layout == "NCHW"
-            else y[:Co, :OHo, :, :N])
+    y = conv_stack_pallas(xf, w1t, b1v, mask, w2t, F1=F1q, F2=F2, S2=stride2,
+                          pitch=pitch, nt=nt, Ho2=Ho2, Wo2=Wo2,
+                          relu1=relu1, bias2=b2v, res=res, epilogue=ep,
+                          out_dtype=cdt,
+                          interpret=interpret)
+    PHo, PWo = flat.pool_geometry(Ho2, Wo2, pool)
+    return flat.unprep(y, N, Co, PHo, PWo, nt, dst_layout)
 
 
-def _stack_bwd_unfused(prims, g, *, engine, stride1, pad1, stride2, pad2,
+@partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 19)))
+def _stack_vjp(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2, nt,
+               interpret, relu1, relu2, pool, src_layout, dst_layout,
+               res_layout, engine):
+    return _stack_core(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2,
                        nt, interpret, relu1, relu2, pool, src_layout,
-                       dst_layout, res_layout):
+                       dst_layout, res_layout, engine)
+
+
+def _stack_fwd(x, w1, b1, w2, b2, res, *static):
+    return (_stack_core(x, w1, b1, w2, b2, res, *static),
+            (x, w1, b1, w2, b2, res))
+
+
+def _stack_bwd(stride1, pad1, stride2, pad2, nt, interpret, relu1, relu2,
+               pool, src_layout, dst_layout, res_layout, engine, prims, g):
     """Stack backward = VJP of the UNFUSED two-conv composition: y1 is
     recomputed with one fused conv1 call (gradient-checkpoint style) and the
-    gradient then flows through the existing layout-aware single-conv custom
-    VJPs (Pallas dgrad/wgrad/pool-backward) — fused-forward memory wins,
+    gradient then flows through ``conv_fused``'s layout-aware custom VJP
+    (Pallas dgrad/wgrad/pool-backward) — fused-forward memory wins,
     unfused-backward correctness (DESIGN.md §12)."""
     x, w1, b1, w2, b2, res = prims
-    conv = (conv_direct_chwn if engine == "CHWN" else conv_im2col_nchw_fused)
-    kw1 = dict(stride=stride1, pad=pad1, interpret=interpret, relu=relu1,
-               src_layout=src_layout, dst_layout=engine)
-    kw2 = dict(stride=stride2, pad=pad2, interpret=interpret, relu=relu2,
-               pool=pool, res_layout=res_layout, src_layout=engine,
+    kw1 = dict(stride=stride1, pad=pad1, engine=engine, nt=nt,
+               interpret=interpret, relu=relu1, src_layout=src_layout,
+               dst_layout=engine)
+    kw2 = dict(stride=stride2, pad=pad2, engine=engine, nt=nt,
+               interpret=interpret, relu=relu2, pool=pool,
+               res_layout=res_layout, src_layout=engine,
                dst_layout=dst_layout)
-    if engine == "CHWN":
-        kw1["nt"] = kw2["nt"] = nt
 
     diff = {"x": x, "w1": w1, "w2": w2}
     for k, v in (("b1", b1), ("b2", b2), ("res", res)):
@@ -470,8 +322,9 @@ def _stack_bwd_unfused(prims, g, *, engine, stride1, pad1, stride2, pad2,
             diff[k] = v
 
     def unfused(d):
-        y1 = conv(d["x"], d["w1"], bias=d.get("b1"), **kw1)
-        return conv(y1, d["w2"], bias=d.get("b2"), res=d.get("res"), **kw2)
+        y1 = conv_fused(d["x"], d["w1"], bias=d.get("b1"), **kw1)
+        return conv_fused(y1, d["w2"], bias=d.get("b2"), res=d.get("res"),
+                          **kw2)
 
     _, vjp = jax.vjp(unfused, diff)
     (gd,) = vjp(g)
@@ -479,112 +332,38 @@ def _stack_bwd_unfused(prims, g, *, engine, stride1, pad1, stride2, pad2,
             gd.get("res"))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 18)))
-def _stack_chwn_vjp(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2,
-                    nt, interpret, relu1, relu2, pool, src_layout,
-                    dst_layout, res_layout):
-    return _stack_core(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2,
-                       nt, interpret, relu1, relu2, pool, src_layout,
-                       dst_layout, res_layout, "CHWN")
-
-
-def _stack_chwn_fwd(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2,
-                    nt, interpret, relu1, relu2, pool, src_layout,
-                    dst_layout, res_layout):
-    y = _stack_core(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2,
-                    nt, interpret, relu1, relu2, pool, src_layout,
-                    dst_layout, res_layout, "CHWN")
-    return y, (x, w1, b1, w2, b2, res)
-
-
-def _stack_chwn_bwd(stride1, pad1, stride2, pad2, nt, interpret, relu1,
-                    relu2, pool, src_layout, dst_layout, res_layout,
-                    prims, g):
-    return _stack_bwd_unfused(prims, g, engine="CHWN", stride1=stride1,
-                              pad1=pad1, stride2=stride2, pad2=pad2, nt=nt,
-                              interpret=interpret, relu1=relu1, relu2=relu2,
-                              pool=pool, src_layout=src_layout,
-                              dst_layout=dst_layout, res_layout=res_layout)
-
-
-_stack_chwn_vjp.defvjp(_stack_chwn_fwd, _stack_chwn_bwd)
-
-
-@partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 18)))
-def _stack_nchw_vjp(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2,
-                    nt, interpret, relu1, relu2, pool, src_layout,
-                    dst_layout, res_layout):
-    return _stack_core(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2,
-                       nt, interpret, relu1, relu2, pool, src_layout,
-                       dst_layout, res_layout, "NCHW")
-
-
-def _stack_nchw_fwd(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2,
-                    nt, interpret, relu1, relu2, pool, src_layout,
-                    dst_layout, res_layout):
-    y = _stack_core(x, w1, b1, w2, b2, res, stride1, pad1, stride2, pad2,
-                    nt, interpret, relu1, relu2, pool, src_layout,
-                    dst_layout, res_layout, "NCHW")
-    return y, (x, w1, b1, w2, b2, res)
-
-
-def _stack_nchw_bwd(stride1, pad1, stride2, pad2, nt, interpret, relu1,
-                    relu2, pool, src_layout, dst_layout, res_layout,
-                    prims, g):
-    return _stack_bwd_unfused(prims, g, engine="NCHW", stride1=stride1,
-                              pad1=pad1, stride2=stride2, pad2=pad2, nt=nt,
-                              interpret=interpret, relu1=relu1, relu2=relu2,
-                              pool=pool, src_layout=src_layout,
-                              dst_layout=dst_layout, res_layout=res_layout)
-
-
-_stack_nchw_vjp.defvjp(_stack_nchw_fwd, _stack_nchw_bwd)
+_stack_vjp.defvjp(_stack_fwd, _stack_bwd)
 
 
 @partial(jax.jit, static_argnames=("stride1", "pad1", "stride2", "pad2",
-                                   "nt", "interpret", "relu1", "relu2",
-                                   "pool", "src_layout", "dst_layout",
-                                   "res_layout"))
-def conv_stack_chwn(x, w1, w2, stride1: int = 1, pad1: int = 0,
-                    stride2: int = 1, pad2: int = 0, nt: int = 128,
-                    interpret: bool = True, *, bias1=None, bias2=None,
-                    relu1: bool = True, relu2: bool = False,
-                    pool: Optional[Tuple[int, int, str]] = None,
-                    res=None, res_layout: str = "CHWN",
-                    src_layout: str = "CHWN", dst_layout: str = "CHWN"):
-    """Fused conv->conv stack, CHWN engine: x [Ci,H,W,N] (or [N,Ci,H,W] for
-    src NCHW), w1 [Ci,F1,F1,Cm], w2 [Cm,F2,F2,Co] -> [Co,Ho2',Wo2',N] (or
-    NCHW for dst NCHW).  Conv1 carries a bias[+ReLU]-only epilogue; conv2
-    takes the full bias/residual-add/ReLU/pool protocol.  The intermediate
-    activation stays in VMEM.  Differentiable: the custom VJP replays the
-    unfused two-conv composition (see ``_stack_bwd_unfused``)."""
-    return _stack_chwn_vjp(x, w1, bias1, w2, bias2, res, stride1, pad1,
-                           stride2, pad2, nt, interpret, relu1, relu2, pool,
-                           src_layout, dst_layout, res_layout)
-
-
-@partial(jax.jit, static_argnames=("stride1", "pad1", "stride2", "pad2",
-                                   "interpret", "relu1", "relu2", "pool",
-                                   "src_layout", "dst_layout", "res_layout"))
-def conv_stack_nchw(x, w1, w2, stride1: int = 1, pad1: int = 0,
-                    stride2: int = 1, pad2: int = 0,
-                    interpret: bool = True, *, bias1=None, bias2=None,
-                    relu1: bool = True, relu2: bool = False,
-                    pool: Optional[Tuple[int, int, str]] = None,
-                    res=None, res_layout: str = "NCHW",
-                    src_layout: str = "NCHW", dst_layout: str = "NCHW"):
-    """Fused conv->conv stack, per-sample im2col-MM NCHW engine: x
-    [N,Ci,H,W] (or [Ci,H,W,N] for src CHWN), w1 [Cm,Ci,F1,F1], w2
-    [Co,Cm,F2,F2] (canonical) -> [N,Co,Ho2',Wo2'] (or CHWN for dst CHWN);
-    otherwise identical to ``conv_stack_chwn``."""
-    return _stack_nchw_vjp(x, w1, bias1, w2, bias2, res, stride1, pad1,
-                           stride2, pad2, 0, interpret, relu1, relu2, pool,
-                           src_layout, dst_layout, res_layout)
+                                   "engine", "nt", "interpret", "relu1",
+                                   "relu2", "pool", "src_layout",
+                                   "dst_layout", "res_layout"))
+def conv_stack(x, w1, w2, stride1: int = 1, pad1: int = 0, stride2: int = 1,
+               pad2: int = 0, *, engine: str, nt: int = flat.CHWN_NT,
+               interpret: Optional[bool] = None, bias1=None, bias2=None,
+               relu1: bool = True, relu2: bool = False,
+               pool: Optional[Tuple[int, int, str]] = None,
+               res=None, res_layout: Optional[str] = None,
+               src_layout: Optional[str] = None,
+               dst_layout: Optional[str] = None):
+    """Fused conv->conv stack on the ``engine`` Pallas path: x in
+    ``src_layout``, w1 [Cm,Ci,F1,F1], w2 [Co,Cm,F2,F2] (canonical) -> output
+    in ``dst_layout`` (layouts default to ``engine``).  Conv1 carries a
+    bias[+ReLU]-only epilogue; conv2 takes the full bias/residual-add/ReLU/
+    pool protocol.  The intermediate activation stays in VMEM.
+    Differentiable: the custom VJP replays the unfused two-conv composition
+    (see ``_stack_bwd``)."""
+    return _stack_vjp(x, w1, bias1, w2, bias2, res, stride1, pad1, stride2,
+                      pad2, nt, interpret, relu1, relu2, pool,
+                      src_layout or engine, dst_layout or engine,
+                      res_layout or engine, engine)
 
 
 @partial(jax.jit, static_argnames=("stride", "pad", "interpret", "use_pallas_mm"))
 def conv_im2col_nchw(x, w, stride: int = 1, pad: int = 0,
-                     interpret: bool = True, use_pallas_mm: bool = True):
+                     interpret: Optional[bool] = None,
+                     use_pallas_mm: bool = True):
     """im2col + matmul, NCHW: x [N,Ci,H,W], w [Co,Ci,F,F] -> [N,Co,Ho,Wo].
     The seed baseline: XLA materializes the patch matrix (the paper's
     'matrix expansion' traffic), only the matmul runs in Pallas."""
@@ -593,7 +372,7 @@ def conv_im2col_nchw(x, w, stride: int = 1, pad: int = 0,
     patches, (n, Ho, Wo) = im2col_nchw(x, F, stride, pad)
     wmat = w.reshape(Co, Ci * F * F).T            # [CiFF, Co]
     if use_pallas_mm:
-        out = matmul(patches, wmat, interpret=interpret)
+        out = matmul(patches, wmat, interpret=resolve_interpret(interpret))
     else:
         out = patches @ wmat
     return out.reshape(N, Ho, Wo, Co).transpose(0, 3, 1, 2)

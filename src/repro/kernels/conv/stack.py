@@ -2,381 +2,139 @@
 
 The biggest HBM round-trip left after epilogue fusion (DESIGN.md §5/§11) is
 the intermediate activation between adjacent convs.  This kernel removes it:
-the first conv consumes a halo-widened input row block (the halo is what the
-SECOND conv's receptive field needs beyond the block boundary), stages its
-post-bias/ReLU output tile in VMEM, and the second conv — with the full
-bias/residual-add/ReLU/pool epilogue protocol — contracts straight off the
-staged tile.  The mid activation never touches HBM; the price is recomputing
-the halo rows of conv1 once per block (DESIGN.md §12).
+the first conv runs over the input slab far enough to cover the SECOND
+conv's receptive field, stages its post-bias/ReLU output in a VMEM scratch,
+and the second conv — with the full bias/residual-add/ReLU/pool epilogue
+protocol — contracts straight off the staged tile.  The mid activation never
+touches HBM (DESIGN.md §12).
 
-Blocking composes the two convs into one virtual conv:
-
-    S_eff = S1 * S2,   F_eff = (F2 - 1) * S1 + F1
-
-so ``conv_blocking(Ho2, F_eff, S_eff)`` yields (bho, IBH, n_ho) with the
-standard halo-stitch guarantee 2*IBH >= (bho-1)*S_eff + F_eff — exactly the
-input span one block of ``mho = (bho-1)*S2 + F2`` mid rows needs.
-
-Padding of the second conv folds into the input: the wrapper pre-pads the
+Both convs use the flat wide form of ``kernels/flat.py`` with one shared row
+pitch: conv1's wide output IS conv2's input slab.  The wrapper pre-pads the
 input by ``pad1 + S1*pad2`` rows/cols per side, which makes the staged mid
 tile exactly ``pad2``-padded y1 — EXCEPT that conv1's epilogue (bias/ReLU)
-is nonzero on the padding rows, so the kernel masks mid rows/cols outside
-the valid global range [pad2, pad2 + Ho1) back to zero before conv2 reads
-them (``jax.lax.broadcasted_iota`` against the block's global row offset).
+is nonzero on the padding ring, so a precomputed 0/1 mask zeroes mid
+positions outside the valid range [pad2, pad2 + Ho1) x [pad2, pad2 + Wo1)
+before conv2 reads them.  Conv1's stride is removed by space-to-depth;
+conv2's stride keeps every ``S2``-th row and column of its stride-1 result.
 
-Both engines are provided, mirroring the single-conv pair: the CHWN variant
-blocks N on the 128 lanes (grid (row blocks, N blocks)); the NCHW variant is
-per-sample (grid (N, row blocks)).  Channels are NOT grid-blocked — the
-full (Ci, Cm, Co) slabs live in VMEM, which is why the planner gates stack
-fusion on a VMEM-footprint bound (``stack_vmem_bytes``) instead.
+Both engines run this kernel (``nt`` samples per slab for CHWN, one for
+NCHW).  Channels are NOT grid-blocked — the full (Ci, Cm, Co) slabs live in
+VMEM, which is why the planner gates stack fusion on a VMEM-footprint bound
+(``stack_vmem_bytes``) instead.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.conv.conv import Epilogue, pool_block, pool_tiles_block
-from repro.shapes import conv_out_hw, pool_out_hw
-
-
-def _mask_mid(mid, h_axis: int, w_axis: int, row0, valid_rows, valid_cols):
-    """Zero mid rows/cols outside the valid global range [pad2, pad2+Ho1):
-    those are conv2's zero padding, but conv1's bias/ReLU made them nonzero.
-    ``row0`` is the block's global mid-row offset; columns are unblocked so
-    their iota is already global."""
-    lo, hi = valid_rows
-    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, mid.shape, h_axis)
-    keep = (rows >= lo) & (rows < hi)
-    lo, hi = valid_cols
-    cols = jax.lax.broadcasted_iota(jnp.int32, mid.shape, w_axis)
-    keep = keep & (cols >= lo) & (cols < hi)
-    return jnp.where(keep, mid, 0.0)
+from repro.kernels import flat, resolve_interpret
+from repro.kernels.conv.conv import Epilogue, _split_refs, finish
 
 
-def _stack_chwn_kernel(*refs, F1, S1, F2, S2, bho, mho, Wm, Wo2,
-                       relu1: bool, epilogue: Epilogue, valid_rows,
-                       valid_cols, src_layout: str, dst_layout: str,
-                       res_layout: str = "CHWN"):
-    xa_ref, xb_ref, w1_ref, b1_ref, w2_ref = refs[:5]
-    rest = refs[5:]
-    b2_ref = r_ref = None
+def stack_lanes(Ho2: int, S2: int, F1: int, F2: int, pitch: int, nt: int):
+    """(mid lanes, conv2 accumulator lanes, input rows) of a stack: conv2's
+    stride-1 wide pass over ``(Ho2-1)*S2 + 1`` rows reads its windows off
+    the staged mid tile, which conv1 fills from the input slab."""
+    total2, _ = flat.conv_lanes((Ho2 - 1) * S2 + 1, F2, pitch, nt)
+    halo2 = flat.ceil_to(flat.tap_offsets(F2, pitch, nt)[-1], 128)
+    total1 = flat.ceil_to(total2 + halo2, flat.CHUNK)
+    halo1 = flat.ceil_to(flat.tap_offsets(F1, pitch, nt)[-1], 128)
+    return total1, total2, -(-(total1 + halo1) // (pitch * nt))
+
+
+def _stack_kernel(x_ref, w1_ref, b1_ref, m_ref, w2_ref, *refs, F1, F2, S2,
+                  pitch, nt, Ho2, Wo2, PWo, relu1: bool, epi: Epilogue,
+                  has_sel: bool, has_sel2: bool):
+    rest = list(refs)
+    sel2_ref = rest.pop(0) if has_sel2 else None
+    b_ref, r_ref, sel_ref, o_ref, _, (mid_ref, acc_ref, y_ref) = \
+        _split_refs(rest, epi, has_sel, False)
+
+    def mid_epilogue(acc, start):
+        y = flat.epilogue(acc, bias=b1_ref[...], relu=relu1)
+        return y * m_ref[:, pl.ds(start, flat.CHUNK)]
+
+    # ---- conv1 over the mid lanes conv2 reads, staged in VMEM ----
+    flat.conv_taps(lambda start, size: x_ref[0, :, pl.ds(start, size)],
+                   w1_ref, mid_ref, F1, pitch, nt, post=mid_epilogue)
+
+    # ---- conv2 straight off the staged tile ----
+    flat.conv_taps(lambda start, size: mid_ref[:, pl.ds(start, size)],
+                   w2_ref, acc_ref, F2, pitch, nt)
+    flat.compact_rows(acc_ref, y_ref, Ho2, S2, pitch, Wo2, nt,
+                      sel=None if sel2_ref is None else sel2_ref[...])
+    finish(y_ref, o_ref, b_ref=b_ref, r_ref=r_ref, z_ref=None,
+           sel_ref=sel_ref, epi=epi, Ho=Ho2, Wo=Wo2, nt=nt, PWo=PWo)
+
+
+def conv_stack_pallas(xf, w1t, b1, mask, w2t, *, F1: int, F2: int, S2: int,
+                      pitch: int, nt: int, Ho2: int, Wo2: int,
+                      relu1: bool = True, bias2=None, res=None,
+                      epilogue: Epilogue = Epilogue(), out_dtype=None,
+                      interpret: Optional[bool] = None):
+    """Fused conv->conv stack on flat slabs.
+
+    xf: [G, Ci, rows*pitch*nt] (``stack_lanes``); w1t: tap-major [F1*F1,
+    Cm, Ci]; b1: [Cm, 1] f32 (conv1's epilogue is bias[+ReLU] only); mask:
+    [1, mid lanes] f32 validity of the staged mid positions; w2t: [F2*F2,
+    Co, Cm]; ``bias2``/``res``/``epilogue`` follow the single-conv protocol,
+    applied to conv2.  Returns [G, Co, PHo2*PWo2*nt] (post-pool when a pool
+    is fused)."""
+    G, Ci, Lx = xf.shape
+    T1, Cm, _ = w1t.shape
+    T2, Co, _ = w2t.shape
+    odt = out_dtype or jnp.result_type(xf.dtype, w1t.dtype)
+    PHo, PWo = flat.pool_geometry(Ho2, Wo2, epilogue.pool)
+    Lm, total2, rows = stack_lanes(Ho2, S2, F1, F2, pitch, nt)
+    assert mask.shape == (1, Lm), (mask.shape, Lm)
+    assert Lx >= rows * pitch * nt, (Lx, rows, pitch, nt)
+    Lo, Lp = Ho2 * Wo2 * nt, PHo * PWo * nt
+
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda g: (0,) * a.ndim)
+
+    in_specs = [pl.BlockSpec((1, Ci, Lx), lambda g: (g, 0, 0)),
+                whole(w1t), whole(b1), whole(mask), whole(w2t)]
+    operands = [xf, w1t, b1, mask, w2t]
+    has_sel2 = S2 > 1
+    if has_sel2:
+        sel2 = flat.selection(S2, Wo2, nt)
+        in_specs.append(whole(sel2))
+        operands.append(sel2)
     if epilogue.bias:
-        b2_ref, rest = rest[0], rest[1:]
-    if epilogue.residual:
-        r_ref, rest = rest[0], rest[1:]
-    (o_ref,) = rest
-
-    xa = xa_ref[...]                     # [Ci, IBH, W, nt] (CHWN blocks)
-    xb = xb_ref[...]
-    if src_layout == "NCHW":             # blocks arrive [nt, Ci, IBH, W]
-        xa = jnp.transpose(xa, (1, 2, 3, 0))
-        xb = jnp.transpose(xb, (1, 2, 3, 0))
-    x2 = jnp.concatenate([xa, xb], axis=1)
-    if jnp.issubdtype(x2.dtype, jnp.integer):
-        x2 = x2.astype(jnp.float32)      # VMEM dequant (scale folded into w1)
-    w1 = w1_ref[...]                     # [Ci, F1, F1, Cm]
-
-    # ---- conv1 on the halo-widened block: mho staged mid rows ----
-    mid = jnp.zeros((w1.shape[-1], mho, Wm, x2.shape[-1]), jnp.float32)
-    for dy in range(F1):
-        for dx in range(F1):
-            xs = x2[:, dy:dy + (mho - 1) * S1 + 1:S1,
-                    dx:dx + (Wm - 1) * S1 + 1:S1, :]    # [Ci, mho, Wm, nt]
-            mid = mid + jnp.einsum(
-                "chwn,ck->khwn", xs, w1[:, dy, dx, :],
-                preferred_element_type=jnp.float32)
-    mid = mid + b1_ref[...].reshape(-1, 1, 1, 1)
-    if relu1:
-        mid = jnp.maximum(mid, 0.0)
-    mid = _mask_mid(mid, 1, 2, pl.program_id(0) * bho * S2,
-                    valid_rows, valid_cols)
-
-    # ---- conv2 straight off the staged VMEM tile ----
-    w2 = w2_ref[...]                     # [Cm, F2, F2, Co]
-    y = jnp.zeros((w2.shape[-1], bho, Wo2, x2.shape[-1]), jnp.float32)
-    for dy in range(F2):
-        for dx in range(F2):
-            ms = mid[:, dy:dy + (bho - 1) * S2 + 1:S2,
-                     dx:dx + (Wo2 - 1) * S2 + 1:S2, :]  # [Cm, bho, Wo2, nt]
-            y = y + jnp.einsum(
-                "chwn,ck->khwn", ms, w2[:, dy, dx, :],
-                preferred_element_type=jnp.float32)
-
-    if epilogue.bias:
-        y = y + b2_ref[...].reshape(-1, 1, 1, 1)
-    if epilogue.residual:                # folded skip add, pre-ReLU
-        r = r_ref[...]
-        if res_layout == "NCHW":         # block arrives [nt, Co, bho, Wo2]
-            r = jnp.transpose(r, (1, 2, 3, 0))
-        y = y + r.astype(jnp.float32)
-    if epilogue.relu:
-        y = jnp.maximum(y, 0.0)
-    if epilogue.pool is not None:
-        pF, pS, pop = epilogue.pool
-        y = pool_block(y, pF, pS, pop)
-    if dst_layout == "NCHW":
-        y = jnp.transpose(y, (3, 0, 1, 2))
-    o_ref[...] = y.astype(o_ref.dtype)
-
-
-def _stack_nchw_kernel(*refs, F1, S1, F2, S2, bho, mho, Wm, Wo2,
-                       relu1: bool, epilogue: Epilogue, valid_rows,
-                       valid_cols, src_layout: str, dst_layout: str,
-                       res_layout: str = "NCHW"):
-    xa_ref, xb_ref, w1_ref, b1_ref, w2_ref = refs[:5]
-    rest = refs[5:]
-    b2_ref = r_ref = None
-    if epilogue.bias:
-        b2_ref, rest = rest[0], rest[1:]
-    if epilogue.residual:
-        r_ref, rest = rest[0], rest[1:]
-    (o_ref,) = rest
-
-    if src_layout == "CHWN":             # blocks arrive [Ci, IBH, W, 1]
-        xa = xa_ref[...][..., 0]
-        xb = xb_ref[...][..., 0]
-    else:                                # native: [1, Ci, IBH, W]
-        xa = xa_ref[...][0]
-        xb = xb_ref[...][0]
-    x2 = jnp.concatenate([xa, xb], axis=1)      # [Ci, 2*IBH, W]
-    if jnp.issubdtype(x2.dtype, jnp.integer):
-        x2 = x2.astype(jnp.float32)
-    w1 = w1_ref[...]                     # [Cm, Ci, F1, F1] (canonical)
-
-    mid = jnp.zeros((w1.shape[0], mho, Wm), jnp.float32)
-    for dy in range(F1):
-        for dx in range(F1):
-            xs = x2[:, dy:dy + (mho - 1) * S1 + 1:S1,
-                    dx:dx + (Wm - 1) * S1 + 1:S1]       # [Ci, mho, Wm]
-            mid = mid + jnp.einsum(
-                "chw,kc->khw", xs, w1[:, :, dy, dx],
-                preferred_element_type=jnp.float32)
-    mid = mid + b1_ref[...].reshape(-1, 1, 1)
-    if relu1:
-        mid = jnp.maximum(mid, 0.0)
-    mid = _mask_mid(mid, 1, 2, pl.program_id(1) * bho * S2,
-                    valid_rows, valid_cols)
-
-    w2 = w2_ref[...]                     # [Co, Cm, F2, F2]
-    y = jnp.zeros((w2.shape[0], bho, Wo2), jnp.float32)
-    for dy in range(F2):
-        for dx in range(F2):
-            ms = mid[:, dy:dy + (bho - 1) * S2 + 1:S2,
-                     dx:dx + (Wo2 - 1) * S2 + 1:S2]     # [Cm, bho, Wo2]
-            y = y + jnp.einsum(
-                "chw,kc->khw", ms, w2[:, :, dy, dx],
-                preferred_element_type=jnp.float32)
-
-    if epilogue.bias:
-        y = y + b2_ref[...].reshape(-1, 1, 1)
-    if epilogue.residual:
-        r = (r_ref[...][..., 0] if res_layout == "CHWN"
-             else r_ref[...][0])         # -> [Co, bho, Wo2]
-        y = y + r.astype(jnp.float32)
-    if epilogue.relu:
-        y = jnp.maximum(y, 0.0)
-    if epilogue.pool is not None:
-        pF, pS, pop = epilogue.pool
-        y = pool_block(y, pF, pS, pop)
-    if dst_layout == "CHWN":
-        y = y[..., None]                 # [Co, obho, OWo2, 1]
-    else:
-        y = y[None]                      # [1, Co, obho, OWo2]
-    o_ref[...] = y.astype(o_ref.dtype)
-
-
-def conv_stack_chwn_pallas(x, w1, b1, w2, F1: int, S1: int, F2: int,
-                           S2: int, *, bho: int, ibh: int, mho: int,
-                           nt: int = 128, valid_rows, valid_cols,
-                           relu1: bool = True, bias2=None, res=None,
-                           res_layout: str = "CHWN",
-                           epilogue: Epilogue = Epilogue(),
-                           src_layout: str = "CHWN",
-                           dst_layout: str = "CHWN",
-                           interpret: bool = True):
-    """Fused conv->conv stack, CHWN engine.
-
-    x: [Ci, H, W, N] (or [N, Ci, H, W] for src NCHW) pre-padded by ops.py
-    with ``pad1 + S1*pad2`` rows/cols per side plus the halo row block;
-    w1: [Ci, F1, F1, Cm]; b1: [Cm, 1] f32 (conv1's epilogue is bias[+ReLU]
-    only — anything richer keeps the stack unfused); w2: [Cm, F2, F2, Co];
-    ``bias2``/``res``/``epilogue`` follow the single-conv protocol, applied
-    to conv2.  ``valid_rows``/``valid_cols`` = (pad2, pad2 + Ho1/Wo1): the
-    global mid range that is real y1 rather than conv2 zero padding.
-    Result: [Co, Ho2', Wo2', N] (or NCHW for dst NCHW), post-pool heights
-    when a pool epilogue is fused.
-    """
-    if src_layout == "NCHW":
-        N, Ci, H, W = x.shape
-    else:
-        Ci, H, W, N = x.shape
-    Cm, Co = w1.shape[-1], w2.shape[-1]
-    S_eff, F_eff = S1 * S2, (F2 - 1) * S1 + F1
-    Wm = conv_out_hw(W, F1, S1)
-    Wo2 = conv_out_hw(Wm, F2, S2)
-    IBH = ibh
-    if IBH == bho * S_eff:
-        n_ho = conv_out_hw(H, F_eff, S_eff) // bho
-    else:
-        n_ho = 1                  # ibh override: single row block by contract
-        assert 2 * IBH >= (bho - 1) * S_eff + F_eff, (IBH, bho, S_eff, F_eff)
-    assert 2 * IBH >= (mho - 1) * S1 + F1, (IBH, mho, S1, F1)
-
-    obho, OWo = bho, Wo2
-    if epilogue.pool is not None:
-        pF, pS, _ = epilogue.pool
-        assert pool_tiles_block(bho, n_ho, pF, pS), (bho, n_ho, pF, pS)
-        obho = pool_out_hw(bho, pF, pS)
-        OWo = pool_out_hw(Wo2, pF, pS)
-    OHo = n_ho * obho
-
-    if src_layout == "NCHW":
-        in_specs = [
-            pl.BlockSpec((nt, Ci, IBH, W), lambda h, n: (n, 0, h, 0)),
-            pl.BlockSpec((nt, Ci, IBH, W), lambda h, n: (n, 0, h + 1, 0)),
-        ]
-    else:
-        in_specs = [
-            pl.BlockSpec((Ci, IBH, W, nt), lambda h, n: (0, h, 0, n)),
-            pl.BlockSpec((Ci, IBH, W, nt), lambda h, n: (0, h + 1, 0, n)),
-        ]
-    in_specs += [
-        pl.BlockSpec((Ci, F1, F1, Cm), lambda h, n: (0, 0, 0, 0)),
-        pl.BlockSpec((Cm, 1), lambda h, n: (0, 0)),
-        pl.BlockSpec((Cm, F2, F2, Co), lambda h, n: (0, 0, 0, 0)),
-    ]
-    operands = [x, x, w1, b1, w2]
-    if epilogue.bias:
-        assert bias2 is not None
-        in_specs.append(pl.BlockSpec((Co, 1), lambda h, n: (0, 0)))
+        in_specs.append(whole(bias2))
         operands.append(bias2)
     if epilogue.residual:
-        assert res is not None
-        if res_layout == "NCHW":
-            in_specs.append(pl.BlockSpec((nt, Co, bho, Wo2),
-                                         lambda h, n: (n, 0, h, 0)))
-        else:
-            in_specs.append(pl.BlockSpec((Co, bho, Wo2, nt),
-                                         lambda h, n: (0, h, 0, n)))
+        in_specs.append(pl.BlockSpec((1, Co, Lo), lambda g: (g, 0, 0)))
         operands.append(res)
-
-    odt = jnp.result_type(x.dtype, w1.dtype)
-    if dst_layout == "NCHW":
-        out_shape = jax.ShapeDtypeStruct((N, Co, OHo, OWo), odt)
-        out_specs = pl.BlockSpec((nt, Co, obho, OWo),
-                                 lambda h, n: (n, 0, h, 0))
-    else:
-        out_shape = jax.ShapeDtypeStruct((Co, OHo, OWo, N), odt)
-        out_specs = pl.BlockSpec((Co, obho, OWo, nt),
-                                 lambda h, n: (0, h, 0, n))
-
-    kern = functools.partial(_stack_chwn_kernel, F1=F1, S1=S1, F2=F2, S2=S2,
-                             bho=bho, mho=mho, Wm=Wm, Wo2=Wo2, relu1=relu1,
-                             epilogue=epilogue, valid_rows=valid_rows,
-                             valid_cols=valid_cols, src_layout=src_layout,
-                             dst_layout=dst_layout, res_layout=res_layout)
+    has_sel = epilogue.pool is not None and epilogue.pool[1] > 1
+    if has_sel:
+        sel = flat.selection(epilogue.pool[1], PWo, nt)
+        in_specs.append(whole(sel))
+        operands.append(sel)
+    isz = jnp.dtype(xf.dtype).itemsize
+    wsz = jnp.dtype(w1t.dtype).itemsize
+    nbytes = (Ci * Lx * isz + (T1 * Cm * Ci + T2 * Co * Cm) * wsz
+              + 2 * Cm * Lm * 4 + Co * total2 * 4 + 3 * Co * Lo * 4
+              + Co * Lp * 4)
+    kern = functools.partial(_stack_kernel, F1=F1, F2=F2, S2=S2, pitch=pitch,
+                             nt=nt, Ho2=Ho2, Wo2=Wo2, PWo=PWo,
+                             relu1=relu1, epi=epilogue, has_sel=has_sel,
+                             has_sel2=has_sel2)
     return pl.pallas_call(
         kern,
-        out_shape=out_shape,
-        grid=(n_ho, N // nt),
+        out_shape=jax.ShapeDtypeStruct((G, Co, Lp), odt),
+        grid=(G,),
         in_specs=in_specs,
-        out_specs=out_specs,
-        interpret=interpret,
-    )(*operands)
-
-
-def conv_stack_nchw_pallas(x, w1, b1, w2, F1: int, S1: int, F2: int,
-                           S2: int, *, bho: int, ibh: int, mho: int,
-                           valid_rows, valid_cols, relu1: bool = True,
-                           bias2=None, res=None, res_layout: str = "NCHW",
-                           epilogue: Epilogue = Epilogue(),
-                           src_layout: str = "NCHW",
-                           dst_layout: str = "NCHW",
-                           interpret: bool = True):
-    """Fused conv->conv stack, per-sample NCHW (im2col-MM) engine.
-
-    x: [N, Ci, H, W] (or [Ci, H, W, N] for src CHWN), pre-padded as in the
-    CHWN variant; w1: [Cm, Ci, F1, F1], w2: [Co, Cm, F2, F2] (canonical);
-    everything else mirrors ``conv_stack_chwn_pallas``.
-    """
-    if src_layout == "CHWN":
-        Ci, H, W, N = x.shape
-    else:
-        N, Ci, H, W = x.shape
-    Cm, Co = w1.shape[0], w2.shape[0]
-    S_eff, F_eff = S1 * S2, (F2 - 1) * S1 + F1
-    Wm = conv_out_hw(W, F1, S1)
-    Wo2 = conv_out_hw(Wm, F2, S2)
-    IBH = ibh
-    if IBH == bho * S_eff:
-        n_ho = conv_out_hw(H, F_eff, S_eff) // bho
-    else:
-        n_ho = 1
-        assert 2 * IBH >= (bho - 1) * S_eff + F_eff, (IBH, bho, S_eff, F_eff)
-    assert 2 * IBH >= (mho - 1) * S1 + F1, (IBH, mho, S1, F1)
-
-    obho, OWo = bho, Wo2
-    if epilogue.pool is not None:
-        pF, pS, _ = epilogue.pool
-        assert pool_tiles_block(bho, n_ho, pF, pS), (bho, n_ho, pF, pS)
-        obho = pool_out_hw(bho, pF, pS)
-        OWo = pool_out_hw(Wo2, pF, pS)
-    OHo = n_ho * obho
-
-    if src_layout == "CHWN":
-        in_specs = [
-            pl.BlockSpec((Ci, IBH, W, 1), lambda n, h: (0, h, 0, n)),
-            pl.BlockSpec((Ci, IBH, W, 1), lambda n, h: (0, h + 1, 0, n)),
-        ]
-    else:
-        in_specs = [
-            pl.BlockSpec((1, Ci, IBH, W), lambda n, h: (n, 0, h, 0)),
-            pl.BlockSpec((1, Ci, IBH, W), lambda n, h: (n, 0, h + 1, 0)),
-        ]
-    in_specs += [
-        pl.BlockSpec((Cm, Ci, F1, F1), lambda n, h: (0, 0, 0, 0)),
-        pl.BlockSpec((Cm, 1), lambda n, h: (0, 0)),
-        pl.BlockSpec((Co, Cm, F2, F2), lambda n, h: (0, 0, 0, 0)),
-    ]
-    operands = [x, x, w1, b1, w2]
-    if epilogue.bias:
-        assert bias2 is not None
-        in_specs.append(pl.BlockSpec((Co, 1), lambda n, h: (0, 0)))
-        operands.append(bias2)
-    if epilogue.residual:
-        assert res is not None
-        if res_layout == "CHWN":
-            in_specs.append(pl.BlockSpec((Co, bho, Wo2, 1),
-                                         lambda n, h: (0, h, 0, n)))
-        else:
-            in_specs.append(pl.BlockSpec((1, Co, bho, Wo2),
-                                         lambda n, h: (n, 0, h, 0)))
-        operands.append(res)
-
-    odt = jnp.result_type(x.dtype, w1.dtype)
-    if dst_layout == "CHWN":
-        out_shape = jax.ShapeDtypeStruct((Co, OHo, OWo, N), odt)
-        out_specs = pl.BlockSpec((Co, obho, OWo, 1),
-                                 lambda n, h: (0, h, 0, n))
-    else:
-        out_shape = jax.ShapeDtypeStruct((N, Co, OHo, OWo), odt)
-        out_specs = pl.BlockSpec((1, Co, obho, OWo),
-                                 lambda n, h: (n, 0, h, 0))
-
-    kern = functools.partial(_stack_nchw_kernel, F1=F1, S1=S1, F2=F2, S2=S2,
-                             bho=bho, mho=mho, Wm=Wm, Wo2=Wo2, relu1=relu1,
-                             epilogue=epilogue, valid_rows=valid_rows,
-                             valid_cols=valid_cols, src_layout=src_layout,
-                             dst_layout=dst_layout, res_layout=res_layout)
-    return pl.pallas_call(
-        kern,
-        out_shape=out_shape,
-        grid=(N, n_ho),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        interpret=interpret,
+        out_specs=pl.BlockSpec((1, Co, Lp), lambda g: (g, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((Cm, Lm), jnp.float32),
+                        pltpu.VMEM((Co, total2), jnp.float32),
+                        pltpu.VMEM((Co, Lo), jnp.float32)],
+        compiler_params=flat.compiler_params(1, nbytes),
+        interpret=resolve_interpret(interpret),
     )(*operands)

@@ -24,7 +24,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.pool.ops import _pad_axis
+
+def _pad_axis(x, axis, m):
+    p = (-x.shape[axis]) % m
+    if p:
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (0, p)
+        x = jnp.pad(x, pad)
+    return x
 
 
 def _route(x, g, F, S, Ho, Wo, op, ha, wa, relu_mask):

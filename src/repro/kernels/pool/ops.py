@@ -1,116 +1,77 @@
-"""jit'd pooling wrappers + the paper's hill-climbing coarsening auto-tune."""
+"""jit'd pooling wrapper: flat slabs in (``kernels/flat.py``), one pool
+kernel, re-layout out; differentiable through the pool-backward kernel."""
 from __future__ import annotations
 
 from functools import partial
-from typing import Callable, Optional
+from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
-from repro.kernels.pool.pool import pool_chwn_pallas, pool_nchw_pallas
-
-VMEM_BUDGET = 4 * 1024 * 1024
+from repro.kernels import flat, resolve_interpret
+from repro.kernels.pool.pool import pool_pallas
 
 
-def _pad_axis(x, axis, m):
-    p = (-x.shape[axis]) % m
-    if p:
-        pad = [(0, 0)] * x.ndim
-        pad[axis] = (0, p)
-        x = jnp.pad(x, pad)
-    return x
+def _pool_flat(x, F, S, op, nt, layout, dst_layout, interpret):
+    """Forward: flat slabs in, one pool kernel, re-layout out."""
+    xn = flat.to_nchw(x, layout)
+    N, C, H, W = xn.shape
+    xf = flat.prep(xn, "NCHW", pad=0, stride=1, rows=H, cols=W, nt=nt,
+                   cmult=1)
+    y = pool_pallas(xf, F, S, op, H=H, W=W, nt=nt, interpret=interpret)
+    Ho, Wo = flat.pool_geometry(H, W, (F, S, op))
+    return flat.unprep(y, N, C, Ho, Wo, nt, dst_layout)
 
 
-def vmem_bytes_chwn(H, W, nt, itemsize) -> int:
-    return H * W * nt * max(itemsize, 4)
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
+def _pool_vjp(x, F, S, op, nt, layout, dst_layout, interpret):
+    return _pool_flat(x, F, S, op, nt, layout, dst_layout, interpret)
 
 
-def autotune_nt(H: int, W: int, N: int, itemsize: int,
-                measure: Optional[Callable[[int], float]] = None) -> int:
-    """The paper's §V.A hill climb: start at a small expansion factor, keep
-    doubling while the cost improves (or, analytically, while the working set
-    fits VMEM); stop at the first regression."""
-    nt, best = 128, None
-    while nt * 2 <= max(N, 128):
-        cand = nt * 2
-        if measure is not None:
-            c = measure(cand)
-            if best is not None and c >= best:
-                break
-            best = c
-        elif vmem_bytes_chwn(H, W, cand, itemsize) > VMEM_BUDGET:
-            break
-        nt = cand
-    return nt
+def _pool_fwd(x, *static):
+    return _pool_vjp(x, *static), x
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
-def _pool_chwn_vjp(x, F, S, op, nt, dst_layout, interpret):
-    xp = _pad_axis(x, 3, nt)
-    y = pool_chwn_pallas(xp, F, S, op, nt, dst_layout=dst_layout,
-                         interpret=interpret)
-    N = x.shape[3]
-    return y[:N] if dst_layout == "NCHW" else y[..., :N]
-
-
-def _pool_chwn_fwd(x, F, S, op, nt, dst_layout, interpret):
-    return _pool_chwn_vjp(x, F, S, op, nt, dst_layout, interpret), x
-
-
-def _pool_chwn_bwd(F, S, op, nt, dst_layout, interpret, x, g):
+def _pool_bwd(F, S, op, nt, layout, dst_layout, interpret, x, g):
     from repro.kernels.pool.backward import pool_backward
-    dx = pool_backward(x, g, F, S, op, layout="CHWN", g_layout=dst_layout,
-                       interpret=interpret)
+    dx = pool_backward(x, g, F, S, op, layout=layout, g_layout=dst_layout,
+                       interpret=resolve_interpret(interpret))
     return (dx.astype(x.dtype),)
 
 
-_pool_chwn_vjp.defvjp(_pool_chwn_fwd, _pool_chwn_bwd)
+_pool_vjp.defvjp(_pool_fwd, _pool_bwd)
 
 
-@partial(jax.jit, static_argnames=("F", "S", "op", "interpret", "nt",
-                                   "dst_layout"))
+@partial(jax.jit, static_argnames=("F", "S", "op", "layout", "nt",
+                                   "dst_layout", "interpret"))
+def pool_fused(x, F: int, S: int, op: str = "max", *, layout: str,
+               nt: int = 0, dst_layout: Optional[str] = None,
+               interpret: Optional[bool] = None):
+    """Pooling of x in ``layout`` with VMEM window reuse.  CHWN (the
+    preferred layout) shares each slab's lanes among up to ``nt`` samples
+    (``flat.group_tile``); NCHW runs one sample per slab (the paper's
+    inefficient-layout baseline).  ``dst_layout`` writes the result directly
+    in the consumer's layout, replacing a standalone transform pass.
+    Differentiable: the VJP runs the max-mask/avg-scatter Pallas kernel,
+    consuming the cotangent in ``dst_layout`` (the reversed re-layout folds
+    into its input read)."""
+    if layout == "CHWN":
+        _, H, W, N = x.shape
+    else:
+        N, _, H, W = x.shape
+    nt =flat.group_tile(N, layout, nt or flat.CHWN_NT, H * W)
+    return _pool_vjp(x, F, S, op, nt, layout, dst_layout or layout,
+                     interpret)
+
+
 def pool_chwn(x, F: int, S: int, op: str = "max", nt: int = 0,
-              dst_layout: str = "CHWN", interpret: bool = True):
-    """[C,H,W,N] pooling with VMEM window reuse (preferred layout).
-    ``dst_layout="NCHW"`` writes the result directly in the consumer's
-    layout, replacing a standalone transform pass.  Differentiable: the VJP
-    runs the max-mask/avg-scatter Pallas kernel, consuming the cotangent in
-    ``dst_layout`` (the reversed re-layout folds into its input read)."""
-    C, H, W, N = x.shape
-    if nt == 0:
-        nt = autotune_nt(H, W, N, x.dtype.itemsize)
-    nt = min(nt, max(N, 1))
-    return _pool_chwn_vjp(x, F, S, op, nt, dst_layout, interpret)
+              dst_layout: str = "CHWN", interpret: Optional[bool] = None):
+    """``pool_fused`` on [C,H,W,N] input."""
+    return pool_fused(x, F, S, op, layout="CHWN", nt=nt,
+                      dst_layout=dst_layout, interpret=interpret)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
-def _pool_nchw_vjp(x, F, S, op, ct, dst_layout, interpret):
-    xp = _pad_axis(x, 1, ct)
-    y = pool_nchw_pallas(xp, F, S, op, ct, dst_layout=dst_layout,
-                         interpret=interpret)
-    C = x.shape[1]
-    return y[:C] if dst_layout == "CHWN" else y[:, :C]
-
-
-def _pool_nchw_fwd(x, F, S, op, ct, dst_layout, interpret):
-    return _pool_nchw_vjp(x, F, S, op, ct, dst_layout, interpret), x
-
-
-def _pool_nchw_bwd(F, S, op, ct, dst_layout, interpret, x, g):
-    from repro.kernels.pool.backward import pool_backward
-    dx = pool_backward(x, g, F, S, op, layout="NCHW", g_layout=dst_layout,
-                       interpret=interpret)
-    return (dx.astype(x.dtype),)
-
-
-_pool_nchw_vjp.defvjp(_pool_nchw_fwd, _pool_nchw_bwd)
-
-
-@partial(jax.jit, static_argnames=("F", "S", "op", "interpret", "ct",
-                                   "dst_layout"))
-def pool_nchw(x, F: int, S: int, op: str = "max", ct: int = 8,
-              dst_layout: str = "NCHW", interpret: bool = True):
-    """[N,C,H,W] pooling (the paper's inefficient-layout baseline);
-    differentiable like ``pool_chwn``."""
-    ct = min(ct, x.shape[1])
-    return _pool_nchw_vjp(x, F, S, op, ct, dst_layout, interpret)
+def pool_nchw(x, F: int, S: int, op: str = "max",
+              dst_layout: str = "NCHW", interpret: Optional[bool] = None):
+    """``pool_fused`` on [N,C,H,W] input."""
+    return pool_fused(x, F, S, op, layout="NCHW", dst_layout=dst_layout,
+                      interpret=interpret)

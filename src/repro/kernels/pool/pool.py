@@ -1,106 +1,60 @@
-"""Pooling Pallas kernels — the paper's §V.A off-chip-access optimization.
+"""Pooling Pallas kernel — the paper's §V.A off-chip-access optimization.
 
 GPU original: CHWN layout + thread coarsening: each thread produces E output
 elements so overlapping input windows are loaded into registers once
-(hill-climbed E).  TPU adaptation: each program owns one (c, n-tile) slab;
-the full H x W x Nt input block is loaded into VMEM ONCE and every
-overlapping window is computed from it (VMEM plays the register file).  The
-coarsening factor maps to the N-tile width Nt, auto-tuned in ops.py by the
-same hill-climbing rule.  The N dim rides the 128 lanes (the paper's
-coalescing dim).
-
-An NCHW variant is provided for the paper's layout comparison: there the
-window slides along the minormost W (lanes), producing the strided accesses
-the paper measures as uncoalesced — on TPU, sub-tile-width W wastes lanes.
+(hill-climbed E).  TPU adaptation: each program owns one slab of ``nt``
+samples (``kernels/flat.py``); the whole H x W x nt input slab is loaded into
+VMEM ONCE and every overlapping window is computed from it (VMEM plays the
+register file).  The CHWN engine interleaves ``nt`` samples on the lanes
+(the paper's coalescing dim); the NCHW engine pools one sample per slab,
+with the window sliding along the lanes.
 """
 from __future__ import annotations
 
+import functools
+from typing import Optional
+
 import jax
-import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.shapes import pool_out_hw
+from repro.kernels import flat, resolve_interpret
 
 
-def _pool_chwn_kernel(x_ref, o_ref, *, F, S, op, Ho, Wo, dst_layout):
-    x = x_ref[...].astype(jnp.float32)          # [1, H, W, Nt]
-    init = -jnp.inf if op == "max" else 0.0
-    acc = jnp.full((1, Ho, Wo, x.shape[-1]), init, jnp.float32)
-    for dy in range(F):
-        for dx in range(F):
-            win = x[:, dy:dy + (Ho - 1) * S + 1:S, dx:dx + (Wo - 1) * S + 1:S, :]
-            acc = jnp.maximum(acc, win) if op == "max" else acc + win
-    if op == "avg":
-        acc = acc / (F * F)
-    if dst_layout == "NCHW":
-        acc = jnp.transpose(acc, (3, 0, 1, 2))  # [Nt, 1, Ho, Wo]
-    o_ref[...] = acc.astype(o_ref.dtype)
+def _pool_kernel(x_ref, *refs, W, nt, F, S, op, Ho, Wo, has_sel):
+    rest = list(refs)
+    sel_ref = rest.pop(0) if has_sel else None
+    (o_ref,) = rest
+    rl = Wo * nt
+
+    def store(r, v):
+        o_ref[0, :, r * rl:(r + 1) * rl] = v.astype(o_ref.dtype)
+
+    flat.pool_rows(lambda off, size: x_ref[0, :, off:off + size], store,
+                   W, nt, F, S, op, Ho, Wo,
+                   sel=None if sel_ref is None else sel_ref[...])
 
 
-def pool_chwn_pallas(x, F: int, S: int, op: str = "max", nt: int = 128,
-                     dst_layout: str = "CHWN", interpret: bool = True):
-    """x: [C, H, W, N] -> [C, Ho, Wo, N] (or [N, C, Ho, Wo] when
-    ``dst_layout == "NCHW"``: the re-layout folds into the output write via
-    the out BlockSpec index map).  N % nt == 0."""
-    C, H, W, N = x.shape
-    Ho = pool_out_hw(H, F, S)          # shared with the selector's byte model
-    Wo = pool_out_hw(W, F, S)
-    import functools
-    kern = functools.partial(_pool_chwn_kernel, F=F, S=S, op=op, Ho=Ho, Wo=Wo,
-                             dst_layout=dst_layout)
-    if dst_layout == "NCHW":
-        out_shape = jax.ShapeDtypeStruct((N, C, Ho, Wo), x.dtype)
-        out_specs = pl.BlockSpec((nt, 1, Ho, Wo), lambda c, n: (n, c, 0, 0))
-    else:
-        out_shape = jax.ShapeDtypeStruct((C, Ho, Wo, N), x.dtype)
-        out_specs = pl.BlockSpec((1, Ho, Wo, nt), lambda c, n: (c, 0, 0, n))
+def pool_pallas(xf, F: int, S: int, op: str, *, H: int, W: int, nt: int,
+                interpret: Optional[bool] = None):
+    """xf: flat [G, C, H*W*nt] -> [G, C, Ho*Wo*nt] (F x F / S windows)."""
+    G, C, L = xf.shape
+    Ho, Wo = flat.pool_geometry(H, W, (F, S, op))
+    in_specs = [pl.BlockSpec((1, C, L), lambda g: (g, 0, 0))]
+    operands = [xf]
+    has_sel = S > 1
+    if has_sel:
+        sel = flat.selection(S, Wo, nt)
+        in_specs.append(pl.BlockSpec(sel.shape, lambda g: (0, 0)))
+        operands.append(sel)
+    nbytes = C * L * (xf.dtype.itemsize + 4) + 2 * C * Ho * Wo * nt * 4
+    kern = functools.partial(_pool_kernel, W=W, nt=nt, F=F, S=S, op=op,
+                             Ho=Ho, Wo=Wo, has_sel=has_sel)
     return pl.pallas_call(
         kern,
-        out_shape=out_shape,
-        grid=(C, N // nt),
-        in_specs=[pl.BlockSpec((1, H, W, nt), lambda c, n: (c, 0, 0, n))],
-        out_specs=out_specs,
-        interpret=interpret,
-    )(x)
-
-
-def _pool_nchw_kernel(x_ref, o_ref, *, F, S, op, Ho, Wo, dst_layout):
-    x = x_ref[...].astype(jnp.float32)          # [1, Ct, H, W]
-    init = -jnp.inf if op == "max" else 0.0
-    acc = jnp.full((1, x.shape[1], Ho, Wo), init, jnp.float32)
-    for dy in range(F):
-        for dx in range(F):
-            win = x[:, :, dy:dy + (Ho - 1) * S + 1:S, dx:dx + (Wo - 1) * S + 1:S]
-            acc = jnp.maximum(acc, win) if op == "max" else acc + win
-    if op == "avg":
-        acc = acc / (F * F)
-    if dst_layout == "CHWN":
-        acc = jnp.transpose(acc, (1, 2, 3, 0))  # [Ct, Ho, Wo, 1]
-    o_ref[...] = acc.astype(o_ref.dtype)
-
-
-def pool_nchw_pallas(x, F: int, S: int, op: str = "max", ct: int = 8,
-                     dst_layout: str = "NCHW", interpret: bool = True):
-    """x: [N, C, H, W] -> [N, C, Ho, Wo] (or [C, Ho, Wo, N] when
-    ``dst_layout == "CHWN"``).  C % ct == 0.  The W dim (lanes) is
-    window-strided — the layout the paper shows to be memory-inefficient."""
-    N, C, H, W = x.shape
-    Ho = pool_out_hw(H, F, S)          # shared with the selector's byte model
-    Wo = pool_out_hw(W, F, S)
-    import functools
-    kern = functools.partial(_pool_nchw_kernel, F=F, S=S, op=op, Ho=Ho, Wo=Wo,
-                             dst_layout=dst_layout)
-    if dst_layout == "CHWN":
-        out_shape = jax.ShapeDtypeStruct((C, Ho, Wo, N), x.dtype)
-        out_specs = pl.BlockSpec((ct, Ho, Wo, 1), lambda n, c: (c, 0, 0, n))
-    else:
-        out_shape = jax.ShapeDtypeStruct((N, C, Ho, Wo), x.dtype)
-        out_specs = pl.BlockSpec((1, ct, Ho, Wo), lambda n, c: (n, c, 0, 0))
-    return pl.pallas_call(
-        kern,
-        out_shape=out_shape,
-        grid=(N, C // ct),
-        in_specs=[pl.BlockSpec((1, ct, H, W), lambda n, c: (n, c, 0, 0))],
-        out_specs=out_specs,
-        interpret=interpret,
-    )(x)
+        out_shape=jax.ShapeDtypeStruct((G, C, Ho * Wo * nt), xf.dtype),
+        grid=(G,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, C, Ho * Wo * nt), lambda g: (g, 0, 0)),
+        compiler_params=flat.compiler_params(1, nbytes),
+        interpret=resolve_interpret(interpret),
+    )(*operands)

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -50,14 +51,14 @@ _softmax_vjp.defvjp(_softmax_fwd, _softmax_bwd)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def softmax(x, interpret: bool = True):
+def softmax(x, interpret: Optional[bool] = None):
     """Fused row softmax for [N, C] (paper §V.B single-kernel);
     differentiable via the closed-form softmax VJP on the saved output."""
     return _softmax_vjp(x, interpret)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def softmax_xent(x, labels, interpret: bool = True):
+def softmax_xent(x, labels, interpret: Optional[bool] = None):
     """Fused softmax+NLL rows: x [N, C], labels [N] -> [N] f32."""
     N, C = x.shape
     bn = pick_bn(N, C, 4)

@@ -11,9 +11,13 @@ block.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _softmax_kernel(x_ref, o_ref):
@@ -24,7 +28,7 @@ def _softmax_kernel(x_ref, o_ref):
     o_ref[...] = (e / s).astype(o_ref.dtype)     # step 5
 
 
-def softmax_pallas(x, bn: int, interpret: bool = True):
+def softmax_pallas(x, bn: int, interpret: Optional[bool] = None):
     """Row softmax of x: [N, C];  N % bn == 0 (ops pads)."""
     N, C = x.shape
     return pl.pallas_call(
@@ -33,7 +37,7 @@ def softmax_pallas(x, bn: int, interpret: bool = True):
         grid=(N // bn,),
         in_specs=[pl.BlockSpec((bn, C), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bn, C), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
 
 
@@ -50,7 +54,8 @@ def _softmax_xent_kernel(x_ref, lab_ref, loss_ref):
     loss_ref[...] = lse - gold
 
 
-def softmax_xent_pallas(x, labels, bn: int, interpret: bool = True):
+def softmax_xent_pallas(x, labels, bn: int,
+                        interpret: Optional[bool] = None):
     """Row-wise cross entropy: x [N, C], labels [N] -> loss [N]."""
     N, C = x.shape
     return pl.pallas_call(
@@ -60,5 +65,5 @@ def softmax_xent_pallas(x, labels, bn: int, interpret: bool = True):
         in_specs=[pl.BlockSpec((bn, C), lambda i: (i, 0)),
                   pl.BlockSpec((bn,), lambda i: (i,))],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, labels)

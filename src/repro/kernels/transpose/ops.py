@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,7 +47,7 @@ def _pad_to(x, m0: int, m1: int):
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def transpose2d(x, interpret: bool = True):
+def transpose2d(x, interpret: Optional[bool] = None):
     """[M, N] -> [N, M] via the tiled Pallas kernel."""
     M, N = x.shape
     bm, bn = pick_blocks(M, N, x.dtype)
@@ -56,7 +57,7 @@ def transpose2d(x, interpret: bool = True):
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def transpose2d_batched(x, interpret: bool = True):
+def transpose2d_batched(x, interpret: Optional[bool] = None):
     """[B, M, N] -> [B, N, M]."""
     B, M, N = x.shape
     bm, bn = pick_blocks(M, N, x.dtype)

@@ -13,16 +13,21 @@ encode.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _transpose_kernel(x_ref, o_ref):
     o_ref[...] = x_ref[...].T
 
 
-def transpose2d_pallas(x, bm: int, bn: int, interpret: bool = True):
+def transpose2d_pallas(x, bm: int, bn: int,
+                       interpret: Optional[bool] = None):
     """x: [M, N] -> [N, M].  M % bm == 0 and N % bn == 0 (ops.py pads)."""
     M, N = x.shape
     grid = (M // bm, N // bn)
@@ -32,7 +37,7 @@ def transpose2d_pallas(x, bm: int, bn: int, interpret: bool = True):
         grid=grid,
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((bn, bm), lambda i, j: (j, i)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
 
 
@@ -40,7 +45,8 @@ def _batched_kernel(x_ref, o_ref):
     o_ref[...] = jnp.swapaxes(x_ref[...], 1, 2)
 
 
-def transpose2d_batched_pallas(x, bm: int, bn: int, interpret: bool = True):
+def transpose2d_batched_pallas(x, bm: int, bn: int,
+                               interpret: Optional[bool] = None):
     """x: [B, M, N] -> [B, N, M] (batched tile transpose)."""
     B, M, N = x.shape
     grid = (B, M // bm, N // bn)
@@ -50,5 +56,5 @@ def transpose2d_batched_pallas(x, bm: int, bn: int, interpret: bool = True):
         grid=grid,
         in_specs=[pl.BlockSpec((1, bm, bn), lambda b, i, j: (b, i, j))],
         out_specs=pl.BlockSpec((1, bn, bm), lambda b, i, j: (b, j, i)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)

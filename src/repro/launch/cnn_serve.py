@@ -47,6 +47,7 @@ import argparse
 import dataclasses
 import logging
 import os
+import tempfile
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
@@ -63,7 +64,9 @@ from repro.cnn.network import batch_output_ok, forward_fused, input_shape
 from repro.distributed.cnn_mesh import (cnn_data_mesh, forward_fused_sharded,
                                         replicate_params)
 from repro.dtypes import canon_dtype, dtype_bytes, jnp_dtype
+from repro.kernels import resolve_interpret
 from repro.perfmodel import Thresholds, calibrate, hardware_id
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import StragglerWatchdog
 from repro.runtime.resilience import (FaultInjector, IncidentLog,
                                       InjectedKernelFault, Rung,
@@ -138,11 +141,17 @@ class CNNServer:
     PER-SHARD batch (``max_bucket`` bounds the shard bucket; admission
     drains up to ``max_bucket * devices`` requests per step).  The §14
     ladder, incident counters, and re-queue semantics operate on the whole
-    shard-group batch, unchanged."""
+    shard-group batch, unchanged.
 
-    def __init__(self, network: str = "lenet", *, reduced: bool = True,
-                 max_bucket: int = 64, impl: str = "xla",
-                 interpret: bool = True, cache_path: Optional[str] = None,
+    The network is served at its published size unless ``reduced`` (96 px
+    images for the big nets — a CPU-sized variant).  ``interpret=None``
+    follows the backend (``resolve_interpret``): compiled Mosaic kernels on
+    a TPU, the Pallas interpreter elsewhere."""
+
+    def __init__(self, network: str = "lenet", *, reduced: bool = False,
+                 max_bucket: int = 64, impl: str = "pallas",
+                 interpret: Optional[bool] = None,
+                 cache_path: Optional[str] = None,
                  calibration: str = "measured",
                  thresholds: Optional[Thresholds] = None,
                  calib_path: Optional[str] = None,
@@ -164,7 +173,7 @@ class CNNServer:
                 cfg = cfg.replace(image_hw=96)
         self.cfg = cfg
         self.impl = impl
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.dtype = canon_dtype(dtype)
         if dtype_policy not in ("uniform", "mixed"):
             raise ValueError(f"unknown dtype policy {dtype_policy!r}")
@@ -191,7 +200,7 @@ class CNNServer:
         # threshold rows are versioned by hardware id (DESIGN.md §13): a
         # cache file carried to a different accelerator keeps its old rows
         # under their id and measures fresh rows for this one
-        self._hw = hardware_id(interpret)
+        self._hw = hardware_id(self.interpret)
         # build the cache first: a persisted cache already carries the
         # per-dtype threshold rows it was planned under, so calibration (the
         # ~4 s measured sweep) only runs when neither the caller nor the
@@ -221,7 +230,7 @@ class CNNServer:
             if calibration == "measured":
                 self.cache.set_thresholds(
                     measured_thresholds(
-                        calib_path, dtype=row, interpret=interpret,
+                        calib_path, dtype=row, interpret=self.interpret,
                         hardware=self._hw,
                         on_corrupt=lambda dst, e: self.incidents.record(
                             "corrupt_state",
@@ -369,8 +378,11 @@ class CNNServer:
                 # handler: any execution failure steps down the ladder
                 kind = ("nonfinite" if isinstance(e, NonFiniteOutput)
                         else "kernel_fault")
+                # logged at WARNING by the incident log: type + first line
+                first = (str(e).splitlines() or [""])[0]
                 self.incidents.record(
-                    kind, f"bucket={bucket} rung={rung.name}: {e}")
+                    kind, f"bucket={bucket} rung={rung.name}: "
+                    f"{type(e).__name__}: {first}")
                 rep = self.reports.setdefault(bucket, BucketReport(bucket))
                 rep.failures += 1
                 qk = self._qkey(bucket, rung)
@@ -426,7 +438,9 @@ class CNNServer:
         rep.rung = res.rung.name
         if res.rung_index > 0:
             rep.degraded += 1
-            self.incidents.record("degraded")
+            self.incidents.record(
+                "degraded", f"bucket={res.bucket} served by rung "
+                f"{res.rung_index} ({res.rung.name})")
         # §14 satellite: serving and training share one anomaly detector —
         # per-batch wall time feeds the bucket's StragglerWatchdog; a
         # flagged bucket is an incident and a report line, the response
@@ -538,7 +552,7 @@ def main():
     ap.add_argument("--network", default="lenet", choices=list(CNN_CONFIGS))
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--max-bucket", type=int, default=32)
-    ap.add_argument("--impl", default="xla", choices=["xla", "pallas"])
+    ap.add_argument("--impl", default="pallas", choices=["xla", "pallas"])
     ap.add_argument("--dtype", default="float32",
                     choices=["float32", "fp32", "bfloat16", "bf16"],
                     help="storage dtype: bf16 halves HBM bytes and plans "
@@ -554,7 +568,9 @@ def main():
                          "many chips (§15); plans are made for the "
                          "per-shard bucket, so Nt flips taken at the shard "
                          "batch are honored")
-    ap.add_argument("--cache-dir", default="/tmp/repro_serve")
+    ap.add_argument("--cache-dir",
+                    default=os.path.join(tempfile.gettempdir(),
+                                         "repro_serve"))
     ap.add_argument("--max-plans", type=int, default=None,
                     help="LRU bound on cached plans per engine (default: "
                          "unbounded)")
@@ -571,6 +587,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
     os.makedirs(args.cache_dir, exist_ok=True)
     srv = CNNServer(
         args.network, max_bucket=args.max_bucket, impl=args.impl,

@@ -11,12 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax < 0.6 has no jax.sharding.AxisType; Auto is the default there, so
-    # passing nothing is equivalent
-    if hasattr(jax.sharding, "AxisType"):
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=axis_types)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -544,8 +544,7 @@ def moe_fwd_a2a(p, x, cfg: ModelConfig, ctx):
         in_specs += [P(F, None), P(F, None), P(None, F)]
 
     manual_axes = set(a for a in (ctx.batch_axes or ())) | {tp}
-    from repro.compat import shard_map as _shard_map
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=tuple(in_specs),
         out_specs=(x_spec, P()),

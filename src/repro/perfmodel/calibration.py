@@ -49,13 +49,15 @@ log = logging.getLogger("repro.perfmodel.calibration")
 DEFAULT_HARDWARE = "default"
 
 
-def hardware_id(interpret: bool = True) -> str:
+def hardware_id(interpret: Optional[bool] = None) -> str:
     """Stable identity of the silicon a measurement ran on.  Interpreter
     timings get their own rows: they measure the Pallas *interpreter* on the
-    host CPU, and must never be mistaken for compiled-TPU thresholds."""
+    host CPU, and must never be mistaken for compiled-TPU thresholds.
+    ``interpret=None`` follows ``resolve_interpret`` (compiled on a TPU)."""
     import jax
+    from repro.kernels import resolve_interpret
     kind = jax.devices()[0].device_kind
-    return f"{kind}/interpret" if interpret else kind
+    return f"{kind}/interpret" if resolve_interpret(interpret) else kind
 
 
 # ---------------------------------------------------------------------------
@@ -220,7 +222,7 @@ def load_thresholds(path: str, dtype: str = DEFAULT_DTYPE,
 
 
 def pallas_conv_measure(*, proxy_hw: int = 8, proxy_co: int = 32,
-                        reps: int = 2, interpret: bool = True,
+                        reps: int = 2, interpret: Optional[bool] = None,
                         dtype: str = DEFAULT_DTYPE
                         ) -> Callable[[ConvLayer, str], float]:
     """Build a ``measure(layer, layout) -> seconds`` callback that times the
@@ -287,7 +289,7 @@ def proxied_layer(l: ConvLayer, *, proxy_hw: int = 8,
 def measured_thresholds(path: Optional[str] = None, *,
                         dtype: str = DEFAULT_DTYPE, force: bool = False,
                         measure: Optional[Callable[[ConvLayer, str], float]]
-                        = None, interpret: bool = True,
+                        = None, interpret: Optional[bool] = None,
                         hardware: Optional[str] = None,
                         on_corrupt: Optional[
                             Callable[[str, Exception], None]] = None
@@ -377,7 +379,7 @@ def _fit_overlay(pairs: List[Tuple[float, float]]) -> Tuple[float, float]:
 
 def cross_validate(measure: Optional[Callable[[ConvLayer, str], float]]
                    = None, *, dtype: str = DEFAULT_DTYPE,
-                   interpret: bool = True,
+                   interpret: Optional[bool] = None,
                    hardware: Optional[str] = None,
                    proxy_hw: int = 8, proxy_co: int = 32,
                    reps: int = 2,

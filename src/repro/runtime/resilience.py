@@ -299,8 +299,7 @@ class IncidentLog:
             raise ValueError(f"unknown incident kind {kind!r} "
                              f"(taxonomy: {INCIDENT_KINDS})")
         self.counts[kind] = self.counts.get(kind, 0) + n
-        if detail:
-            log.warning("incident %s: %s", kind, detail)
+        log.warning("incident %s%s", kind, f": {detail}" if detail else "")
 
     @property
     def total(self) -> int:

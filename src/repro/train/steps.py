@@ -102,8 +102,7 @@ def make_train_step(cfg: ModelConfig, mesh, parallel: ParallelConfig,
             pspec = jax.tree.map(lambda _: P(), params)
             ospec = jax.tree.map(lambda _: P(), opt_state)
             bspec = {k: P("pod") for k in batch}
-            from repro.compat import shard_map as _shard_map
-            return _shard_map(
+            return jax.shard_map(
                 pod_body, mesh=mesh,
                 in_specs=(pspec, ospec, bspec),
                 out_specs=(pspec, ospec,

@@ -210,7 +210,7 @@ def test_cnn_server_reduces_branching_net_through_builder(tmp_path):
     builder — a bare replace(image_hw=96) zeroes out the 7x7 global pool
     and init_cnn divides by zero on the fc fan-in."""
     from repro.launch.cnn_serve import CNNServer
-    srv = CNNServer(network="resnet18", calibration="analytic",
+    srv = CNNServer(network="resnet18", reduced=True, calibration="analytic",
                     cache_path=str(tmp_path / "cache.json"))
     assert srv.cfg.image_hw <= 96
     shapes = layer_shapes(srv.cfg)

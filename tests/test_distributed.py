@@ -116,8 +116,7 @@ g = jax.random.normal(jax.random.PRNGKey(0), (4, 64, 64)) * 0.01
 def red(gl, mode):
     return compress_psum({{"w": gl}}, "pod", mode)["w"]
 
-from repro.compat import shard_map
-f = shard_map(lambda gl: red(gl, "{mode}"), mesh=mesh,
+f = jax.shard_map(lambda gl: red(gl, "{mode}"), mesh=mesh,
               in_specs=P("pod", None, None), out_specs=P("pod", None, None),
               axis_names={{"pod", "data", "model"}}, check_vma=False)
 with mesh:
@@ -156,8 +155,9 @@ from repro.launch.mesh import make_host_mesh
 
 # host mesh stands in; fit_spec math only uses mesh axis SIZES, so use
 # an abstract mesh with the production sizes
-from repro.compat import abstract_mesh
-mesh = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+from jax.sharding import AbstractMesh, AxisType
+mesh = AbstractMesh((2, 16, 16), ("pod", "data", "model"),
+                    axis_types=(AxisType.Auto,) * 3)
 for arch in ARCH_IDS:
     cfg = get_config(arch)
     specs = param_specs(cfg, mesh, ParallelConfig(fsdp=True, fsdp_pod=True))

@@ -100,12 +100,27 @@ def test_pool_nchw(C, H, W, N, F, S, op):
                                atol=1e-5)
 
 
-def test_pool_autotune_hill_climb():
-    """The §V.A hill climb stops at the first measured regression."""
-    from repro.kernels.pool.ops import autotune_nt
-    costs = {128: 10.0, 256: 8.0, 512: 6.0, 1024: 9.0}
-    nt = autotune_nt(28, 28, 4096, 4, measure=lambda c: costs.get(c, 99.0))
-    assert nt == 512
+@pytest.mark.parametrize("N,nt,lanes,want", [
+    (128, 8, 100, 8),       # CHWN_NT binds
+    (128, 4, 100, 4),       # the caller's nt binds
+    (3, 8, 100, 2),         # the batch binds: largest power of two <= 3
+    (128, 8, 576, 2),       # SLAB_LANES binds (the 24x24 POOL_CASES slab)
+    (128, 128, 729, 2),     # ... and for a 27x27 slab, nt above CHWN_NT
+    (128, 8, 3000, 1),      # one sample overflows alone: still 1
+    (1, 8, 10, 1),
+])
+def test_group_tile(N, nt, lanes, want):
+    """Samples per CHWN slab: the largest power of two within min(nt,
+    CHWN_NT, N) whose slab of ``lanes`` per sample fits SLAB_LANES; the NCHW
+    engine always runs one sample per slab."""
+    from repro.kernels import flat
+    g = flat.group_tile(N, "CHWN", nt, lanes)
+    assert g == want
+    cap = min(nt, flat.CHWN_NT, N)
+    assert g & (g - 1) == 0 and g <= max(1, cap)
+    assert g == 1 or g * lanes <= flat.SLAB_LANES
+    assert 2 * g > cap or 2 * g * lanes > flat.SLAB_LANES    # maximal
+    assert flat.group_tile(N, "NCHW", nt, lanes) == 1
 
 
 # --------------------------------------------------------------------------
