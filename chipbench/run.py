@@ -19,8 +19,10 @@ configuration (``chipbench/configs/<config>.json``) and a traffic mix
    drains the requests that fell due inside the window;
 6. reads peak device memory, frees the server, and compares every served
    request with the plain reference (``chipbench/reference.py``);
-7. prints the numbers compared beside their limits on standard error, and
-   one JSON line on standard output.
+7. prints the server's incidents (a straggler, a step its watchdog timed as
+   slow, is reported there and not compared) and the numbers compared
+   beside their limits on standard error, and one JSON line on standard
+   output.
 
 With ``--trace 0`` the result carries the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics; each metric is read by
@@ -38,6 +40,7 @@ import contextlib  # noqa: E402
 import gc  # noqa: E402
 import glob  # noqa: E402
 import json  # noqa: E402
+import logging  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -55,6 +58,11 @@ from chipbench import spec  # noqa: E402
 # cell in the same checkout compiles nothing
 CACHE_MIN_COMPILE_S = 0.0
 MAX_STEP_FAILURES = 3
+# the incident kind that times a step and finds no fault: the server's
+# watchdog flags a step far above its bucket's running mean.  The window's
+# own timing is what the end-to-end metrics judge, so ``correct`` leaves it
+# out and the run reports it on standard error
+TIMING_INCIDENT = "straggler"
 
 
 class NoChip(RuntimeError):
@@ -63,6 +71,22 @@ class NoChip(RuntimeError):
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
+
+
+class StragglerWarnings(logging.Handler):
+    """Keeps the server's straggler warnings, each with the host-clock time
+    it came at; every other record it prints as Python's default would."""
+
+    def __init__(self):
+        super().__init__(logging.WARNING)
+        self.seen = []           # (perf_counter, message)
+
+    def emit(self, record) -> None:
+        msg = record.getMessage()
+        if msg.startswith("serving straggler"):
+            self.seen.append((time.perf_counter(), msg))
+        else:
+            log(msg)
 
 
 def check_devices(chips: int):
@@ -263,6 +287,10 @@ class Cell:
         else:
             self.sharding = jax.sharding.SingleDeviceSharding(
                 self.devices[0])
+        from repro.launch.cnn_serve import log as server_log
+        self._server_log = server_log
+        self.stragglers = StragglerWarnings()
+        server_log.addHandler(self.stragglers)
 
     def prepare(self, seed: int):
         """Weights and the image pool from ``seed``; warm the buckets."""
@@ -310,6 +338,7 @@ class Cell:
         gc.freeze()
         self.setup_s = time.perf_counter() - PROCESS_START
         loop = Loop(self.srv, self.pool, seed, annotate)
+        self.t0 = loop.t0
         started = []
 
         def on_gc(phase, info):         # each collection's pause, timed
@@ -335,12 +364,20 @@ class Cell:
         return loop
 
     def free_server(self) -> None:
-        self.incidents = self.srv.incidents.total
+        """Read the server's incident counts over its life, then free it.
+        ``incidents`` counts every kind of the program's taxonomy but the
+        timing one."""
+        inc = self.srv.incidents
+        self.incidents = sum(n for k, n in inc.counts.items()
+                             if k != TIMING_INCIDENT)
+        self.timing_incidents = inc.counts.get(TIMING_INCIDENT, 0)
+        self.incident_summary = inc.summary()
         self.srv = None
         gc.unfreeze()
         gc.collect()
 
     def close(self) -> None:
+        self._server_log.removeHandler(self.stragglers)
         shutil.rmtree(self.state, ignore_errors=True)
 
 
@@ -450,6 +487,12 @@ def run(args, *, root: str = ROOT, require_chip: bool = True,
         result["device"]["window_s"] = summary["window_s"]
         result["breakdown"] = summary["breakdown"]
     result["checks"] = checks
+    log(f"incidents over the server's life: {cell.incident_summary}; "
+        f"{cell.timing_incidents} {TIMING_INCIDENT}, reported and not "
+        f"compared")
+    for t, msg in cell.stragglers.seen:
+        log(f"{TIMING_INCIDENT} at {t - cell.t0:.3f}s from the window's "
+            f"start: {msg}")
     for k, v in checks.items():
         log(f"check {k}: {v['value']!r} (limit {v['limit']!r})")
     return result

@@ -1,8 +1,11 @@
 """The harness end to end on the CPU: a cell made of new files only, the
-faults that ``correct`` must catch, and the refusal to run without a
-TPU."""
+faults that ``correct`` must catch, the slow step that it must not, and
+the refusal to run without a TPU."""
+import dataclasses
 import json
 import os
+import re
+import statistics
 import subprocess
 import sys
 
@@ -11,6 +14,7 @@ import pytest
 
 from chipbench import run
 from chipbench.tests.conftest import ROOT
+from repro.runtime.resilience import FaultInjector
 
 
 def run_cell(root, workload, trace=0, fault=None, seconds=1.0):
@@ -85,13 +89,71 @@ def stale_step(srv):
     _wrap_step(srv, alter)
 
 
-@pytest.mark.parametrize("fault", [answer_altered, half_batch_lost,
-                                   stale_step])
+def top_rung_kernel_fault(srv):
+    """Every call of the top rung's kernels fails, as a kernel that faults
+    on the chip: the server quarantines the rung and serves each batch a
+    rung lower, with right answers."""
+    srv.injector = FaultInjector(rates={f"kernel@{srv.ladder[0].name}": 1.0})
+
+
+# the check that each fault has to fail
+CAUGHT_BY = {answer_altered: "prob_gap", half_batch_lost: "prob_gap",
+             stale_step: "prob_gap", top_rung_kernel_fault: "incidents"}
+
+
+@pytest.mark.parametrize("fault", list(CAUGHT_BY))
 def test_a_fault_in_the_timed_path_is_not_correct(bench_root, fault):
     r = run_cell(bench_root, "lenet.closed", fault=fault)
     assert r["correct"] is False
-    assert r["checks"]["prob_gap"]["value"] > r["checks"]["prob_gap"][
-        "limit"]
+    check = r["checks"][CAUGHT_BY[fault]]
+    assert check["value"] > check["limit"]
+
+
+class OneSlowStep:
+    """After thirty steps of the window, one step is stretched by a sleep
+    of twenty median steps, through the injector's ``slow`` site inside the
+    server's timer.  The bucket's watchdog starts afresh at the window:
+    its statistics would hold warm-up's first call, which compiles for
+    seconds in the interpreter, and no sleep a short test can afford would
+    pass a threshold that high.  Thirty, because the watchdog takes the
+    step into its own mean and variance before it compares: over n steps
+    one step stands at most (n - 1) / sqrt(n) deviations above the mean,
+    which passes its four only from n = 18."""
+
+    def __init__(self):
+        self.slept = None
+
+    def __call__(self, srv):
+        srv._watchdogs = {
+            b: dataclasses.replace(wd, _n=0, _mean=0.0, _m2=0.0, flagged=[])
+            for b, wd in srv._watchdogs.items()}
+        run_guarded, seconds = srv._run_guarded, []
+
+        def once(x, batch):
+            if len(seconds) == 30:
+                self.slept = 20 * statistics.median(seconds)
+                srv.injector = FaultInjector(rates={"slow": 1.0},
+                                             slow_s=self.slept)
+            res = run_guarded(x, batch)
+            srv.injector = None
+            seconds.append(res.seconds)
+            return res
+        srv._run_guarded = once
+
+
+def test_a_straggler_is_reported_and_not_compared(bench_root, capsys):
+    fault = OneSlowStep()
+    r = run_cell(bench_root, "lenet.closed", fault=fault, seconds=2.0)
+    err = capsys.readouterr().err
+    assert r["correct"] is True, r["checks"]
+    assert r["checks"]["incidents"]["value"] == 0
+    assert re.search(r"incidents=\d+ \(straggler:\d+\)", err)
+    flagged = [float(s) for s in re.findall(
+        r"straggler at \d+\.\d+s from the window's start: serving "
+        r"straggler: bucket=8 step=\d+ (\d+\.\d+)s", err)]
+    assert fault.slept is not None and flagged
+    assert max(flagged) >= fault.slept
+    assert list(r)[-1] == "checks"
 
 
 def test_no_tpu_exits_non_zero_without_a_result():
