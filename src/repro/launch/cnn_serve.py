@@ -99,6 +99,7 @@ class BucketReport:
     hbm_bytes: int = 0                 # modeled GLOBAL bytes, summed/batch
     per_chip_bytes: int = 0            # modeled per-chip bytes, summed (§15)
     seconds: float = 0.0
+    staged_bytes: int = 0              # host bytes sent to the chip, summed
     degraded: int = 0                  # batches served below the top rung
     failures: int = 0                  # rung attempts that failed (§14)
     rung: str = ""                     # rung that served the LAST batch
@@ -249,6 +250,12 @@ class CNNServer:
         self._fwd = {}                 # (bucket, rung.name) -> jitted fwd
         self._plan_stats = {}          # (bucket, rung.name) -> modeled bytes
         self._watchdogs: Dict[int, StragglerWatchdog] = {}
+        # the admitted batch's host rows: one float32 buffer of
+        # max_bucket * devices images, allocated on the first step and
+        # refilled by every later one, so that no step pays the page
+        # faults of a fresh batch-sized allocation
+        self._staging: Optional[np.ndarray] = None
+        self.staging_allocs = 0
 
     # -- admission -----------------------------------------------------------
 
@@ -280,7 +287,8 @@ class CNNServer:
     def _forward_for(self, bucket: int, rung: Optional[Rung] = None):
         """Jitted forward for (shard bucket, rung) — rung defaults to the
         top of the ladder.  The rung's plan is the PlanCache's own plan for
-        that (policy, stack, devices) variant; the jitted function also
+        that (policy, stack, devices) variant.  The jitted function takes
+        the bucket's rows flat, padded, and also
         returns the §14 finite-check scalar so the guard costs no extra
         device round trip.  Under a mesh (§15) the forward is the sharded
         executor: every shard runs the ONE per-shard-bucket plan, so this
@@ -308,9 +316,16 @@ class CNNServer:
             # _modeled_bytes at the shard config IS the per-chip traffic
             self._plan_stats[key] = self._modeled_bytes(bcfg, plan)
             impl, interp, mesh = rung.impl, self.interpret, self._mesh
+            # every shard's rows, one global batch
+            shape = (bucket * self.devices,) + input_shape(bcfg)[1:]
+            jdtype = self._jdtype
 
             @jax.jit
             def fwd(params, x):
+                # the batch arrives as staged, flat: a 1-D array needs no
+                # host relayout to the device's tiles; the reshape and the
+                # cast to the storage dtype run here, on the device
+                x = x.reshape(shape).astype(jdtype)
                 if mesh is None:
                     y, _ = forward_fused(params, x, bcfg, plan, impl=impl,
                                          interpret=interp)
@@ -334,10 +349,24 @@ class CNNServer:
         (== the plain bucket when devices == 1)."""
         return self.cache.bucket(-(-B // self.devices))
 
+    def _stage(self, batch: List[ImageRequest]) -> np.ndarray:
+        """Copy the admitted images into the server's staging buffer and
+        return their rows as one flat view of it."""
+        c, h = self.cfg.in_channels, self.cfg.image_hw
+        if self._staging is None:
+            self._staging = np.empty(
+                (self.cache.max_bucket * self.devices, c, h, h), np.float32)
+            self.staging_allocs += 1
+        B = len(batch)
+        np.stack([r.image for r in batch], out=self._staging[:B])
+        return self._staging[:B].reshape(-1)
+
     def _run_guarded(self, x_np: np.ndarray, B: int) -> _GuardResult:
-        """Run one admitted batch down the degradation ladder.  Raises
-        ``ServingFault`` only when EVERY rung failed; the caller re-queues
-        the batch before propagating."""
+        """Run one admitted batch, staged flat by ``_stage``, down the
+        degradation ladder.  Raises ``ServingFault`` only when EVERY rung
+        failed; the caller re-queues the batch before propagating.  The
+        staging buffer is not written again before this returns, after
+        ``block_until_ready``: a transfer may alias host memory."""
         bucket = self._shard_bucket(B)
         # skip straight to the first non-quarantined rung; the terminal
         # rung is always eligible (a fully-quarantined bucket still serves)
@@ -346,10 +375,15 @@ class CNNServer:
                      len(self.ladder) - 1)
         delay = self.backoff_s
         errors: List[str] = []
+        t0 = time.perf_counter()
+        # one transfer serves every rung.  It carries the batch's own rows;
+        # the pad rows are zeros added on the device, as `pad_to_bucket`
+        # gives them, so they never cross the host link
+        x = pad_to_bucket(jnp.asarray(x_np),
+                          bucket * self.devices * (x_np.size // B))
         for i in range(start, len(self.ladder)):
             rung = self.ladder[i]
             quals = (rung.name, rung.policy, rung.impl)
-            t0 = time.perf_counter()
             try:
                 if self.injector is not None:
                     self.injector.maybe_slow(quals)
@@ -360,10 +394,7 @@ class CNNServer:
                                                   stack=rung.stack,
                                                   devices=self.devices)
                 fwd = self._forward_for(bucket, rung)
-                xb = jnp.asarray(x_np).astype(self._jdtype)
-                # global pad: every shard gets exactly `bucket` rows
-                y, ok = fwd(self.params,
-                            pad_to_bucket(xb, bucket * self.devices))
+                y, ok = fwd(self.params, x)
                 y = jax.block_until_ready(y)
                 probs = np.asarray(y.astype(jnp.float32))
                 if self.injector is not None:
@@ -396,6 +427,7 @@ class CNNServer:
                 if i + 1 < len(self.ladder) and delay > 0.0:
                     time.sleep(min(delay, 2.0))
                     delay *= 2.0       # exponential backoff down the chain
+                t0 = time.perf_counter()
         raise ServingFault(
             f"all rungs failed for bucket {bucket}: {'; '.join(errors)}")
 
@@ -414,8 +446,8 @@ class CNNServer:
         batch = [self.queue.popleft()
                  for _ in range(min(len(self.queue), cap))]
         B = len(batch)
-        x_np = np.stack([r.image for r in batch])
         try:
+            x_np = self._stage(batch)
             res = self._run_guarded(x_np, B)
         except Exception:
             self.queue.extendleft(reversed(batch))
@@ -431,6 +463,7 @@ class CNNServer:
         rep.batches += 1
         rep.images += B
         rep.padded += res.bucket * self.devices - B
+        rep.staged_bytes += x_np.nbytes
         per_chip = self._plan_stats[(res.bucket, res.rung.name)]
         rep.per_chip_bytes += per_chip
         rep.hbm_bytes += per_chip * self.devices
@@ -527,8 +560,9 @@ class CNNServer:
             dsig = plan.dtype_signature if plan is not None else "(evicted)"
             ips = rep.images / rep.seconds if rep.seconds else 0.0
             perr = (f"{errs[b]:.2f}" if b in errs else "n/a")
-            pcmb = (rep.per_chip_bytes / rep.batches / 1e6
-                    if rep.batches else 0.0)
+            pcmb, smb = ((rep.per_chip_bytes / rep.batches / 1e6,
+                          rep.staged_bytes / rep.batches / 1e6)
+                         if rep.batches else (0.0, 0.0))
             wd = self._watchdogs.get(b)
             lines.append(
                 f"  bucket={b:<4d} batches={rep.batches:<4d} "
@@ -536,14 +570,16 @@ class CNNServer:
                 f"hit_rate={rep.hit_rate:.2f} conv_layouts={sig} "
                 f"conv_dtypes={dsig} "
                 f"modeled_MB={rep.hbm_bytes / 1e6:.1f} "
-                f"per_chip_MB={pcmb:.1f} img/s={ips:.1f} "
+                f"per_chip_MB={pcmb:.1f} staged_MB={smb:.3f} "
+                f"img/s={ips:.1f} "
                 f"pred_err={perr} rung={rep.rung or 'n/a'} "
                 f"degraded={rep.degraded} failures={rep.failures} "
                 f"stragglers={len(wd.flagged) if wd else 0}")
         # §14: the resilience summary — incident taxonomy totals and the
         # quarantined plan variants currently being skipped
         lines.append(f"  {self.incidents.summary()} "
-                     f"quarantined_variants={len(self._quarantine)}")
+                     f"quarantined_variants={len(self._quarantine)} "
+                     f"staging_allocs={self.staging_allocs}")
         return lines
 
 

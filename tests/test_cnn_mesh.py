@@ -144,6 +144,51 @@ print("maxdiff_globalplan=%.3e" % float(jnp.abs(ys - yg).max()))
     assert float(diffs["maxdiff_globalplan"]) <= 1e-5
 
 
+def assert_staged_matches_direct(srv, reqs, done):
+    """Every request a mesh server served, bit-equal to a direct jitted
+    sharded forward over ``pad_to_bucket`` of its admitted batch: the
+    staged flat path changes no output.  ``srv.run`` admits ``reqs`` in
+    order, ``max_bucket * devices`` at a time."""
+    d = srv.devices
+    cap = srv.cache.max_bucket * d
+    for k in range(0, len(reqs), cap):
+        batch = reqs[k:k + cap]
+        bkt = srv._shard_bucket(len(batch))
+        plan = srv.cache.peek_fused(srv.cfg, bkt, dtype=srv.dtype,
+                                    policy=srv.dtype_policy, devices=d,
+                                    pre_sharded=True)
+        x = pad_to_bucket(jnp.asarray(np.stack([r.image for r in batch])),
+                          bkt * d)
+        y = np.asarray(jax.jit(lambda p, x: forward_fused_sharded(
+            p, x, srv.cfg.replace(batch=bkt), plan, srv._mesh,
+            impl="xla"))(srv.params, x))
+        for i, r in enumerate(batch):
+            np.testing.assert_array_equal(done[r.rid], y[i])
+    assert srv.staging_allocs == 1
+
+
+def test_sharded_server_staged_matches_direct_subprocess():
+    """The mesh server at devices=2 through the staged path: a full global
+    batch, then a short one whose last shard is padded."""
+    out = run_with_devices("""
+import numpy as np
+from repro.launch.cnn_serve import CNNServer, ImageRequest
+from tests.test_cnn_mesh import assert_staged_matches_direct
+
+srv = CNNServer("lenet", max_bucket=4, impl="xla", calibration="analytic",
+                devices=2)
+rng = np.random.default_rng(0)
+reqs = [ImageRequest(i, rng.standard_normal((1, 28, 28)).astype(np.float32))
+        for i in range(11)]
+done = srv.run(reqs)
+assert len(done) == len(reqs)
+assert sorted(srv.reports) == [2, 4]
+assert_staged_matches_direct(srv, reqs, done)
+print("staged_ok")
+""", n_devices=2)
+    assert "staged_ok" in out
+
+
 # ---------------------------------------------------------------------------
 # in-process multi-device tier (mesh CI job)
 # ---------------------------------------------------------------------------
@@ -210,3 +255,4 @@ def test_sharded_server_smoke(multi_devices, tmp_path):
                                             devices=d)
         verify_shard_plan(plan, srv.cfg, b, dtype=srv.dtype,
                           policy=srv.dtype_policy)
+    assert_staged_matches_direct(srv, reqs, done)

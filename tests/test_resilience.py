@@ -215,6 +215,79 @@ def test_degraded_output_bit_equal_to_rung(tmp_path, rung_idx):
 
 
 # ---------------------------------------------------------------------------
+# staging: one reused host buffer, sent flat, pad rows zeroed
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_staged_steps_bit_equal_to_direct_forward(tmp_path, dtype):
+    """A full batch, then a short one, through one server and its one
+    staging buffer: each step's forward receives exactly ``pad_to_bucket``
+    of its own batch (zero pad rows, not the first batch's stale images),
+    each request's probabilities are bit-equal to a direct jitted forward
+    over that, and the first step's probabilities survive the second."""
+    srv = make_server(tmp_path, dtype=dtype, thresholds=calibrate(
+        dtype_bytes=4 if dtype == "float32" else 2))
+    sent = []
+    forward_for = srv._forward_for
+
+    def spy(bucket, rung=None):
+        fwd = forward_for(bucket, rung)
+
+        def run(params, x):
+            sent.append(np.array(x))             # what the forward gets
+            return fwd(params, x)
+        return run
+    srv._forward_for = spy
+
+    def direct(batch):
+        bkt = srv._shard_bucket(len(batch))
+        bcfg = srv.cfg.replace(batch=bkt)
+        plan = srv.cache.peek_fused(srv.cfg, bkt, dtype=srv.dtype,
+                                    pre_sharded=True)
+        x = pad_to_bucket(jnp.asarray(np.stack([r.image for r in batch])),
+                          bkt)
+        y = jax.jit(lambda p, x: forward_fused(
+            p, x.astype(srv._jdtype), bcfg, plan, impl="xla")[0])(
+                srv.params, x)
+        return np.asarray(x).ravel(), np.asarray(y.astype(jnp.float32))
+
+    full, short = make_requests(srv.cfg, 8), make_requests(srv.cfg, 3, seed=1)
+    for r in full + short:
+        srv.submit(r)
+    assert len(srv.step()) == 8
+    kept = [r.probs.copy() for r in full]
+    assert len(srv.step()) == 3
+    assert srv.staging_allocs == 1
+    for batch, x_sent in zip((full, short), sent):
+        x, y = direct(batch)
+        np.testing.assert_array_equal(x_sent, x)
+        for i, r in enumerate(batch):
+            np.testing.assert_array_equal(r.probs, y[i])
+    assert sent[1].size == 4 * sent[0].size // 8   # bucket 4: one pad row
+    for r, p in zip(full, kept):
+        np.testing.assert_array_equal(r.probs, p)
+
+
+@pytest.mark.parametrize("B", [8, 5, 3])
+def test_staging_buffer_allocated_once(tmp_path, B):
+    """Ten steps allocate the buffer once, sized by the largest bucket;
+    each step sends its batch's own rows, ``B × C×H×W × 4`` bytes."""
+    srv = make_server(tmp_path)
+    for k in range(10):
+        srv.run(make_requests(srv.cfg, B, seed=k))
+    bkt = srv._shard_bucket(B)
+    c, h = srv.cfg.in_channels, srv.cfg.image_hw
+    rep = srv.reports[bkt]
+    assert srv.staging_allocs == 1
+    assert srv._staging.shape == (8, c, h, h)
+    assert rep.batches == 10
+    assert rep.staged_bytes == 10 * B * c * h * h * 4
+    lines = srv.report_lines()
+    assert f"staged_MB={B * c * h * h * 4 / 1e6:.3f}" in lines[1]
+    assert "staging_allocs=1" in lines[-1]
+
+
+# ---------------------------------------------------------------------------
 # quarantine: skip straight to the known-good rung, planner_calls bounded
 # ---------------------------------------------------------------------------
 
