@@ -1,8 +1,8 @@
 """Compile-only tests for a TPU v5e: the serving path's Pallas kernels at
-AlexNet's published widths, compiled by Mosaic for a described (not
-attached) chip.  Interpret-mode tests cannot see what these catch: blocks
-that break the (8, 128) tiling rule, strided or gathered value slices,
-VMEM overflow.
+AlexNet's published widths, and the whole AlexNet and VGG-16 programs,
+compiled by Mosaic for a described (not attached) chip.  Interpret-mode
+tests cannot see what these catch: blocks that break the (8, 128) tiling
+rule, strided or gathered value slices, VMEM overflow.
 
 The topology is described inside a module-scoped fixture — never at import
 time — so every xdist worker collects the same tests and only the worker
@@ -17,7 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.cnn.layers import init_cnn
 from repro.cnn.network import forward_fused, input_shape, plan_network_fused
-from repro.configs.cnn_networks import ALEXNET
+from repro.configs.cnn_networks import ALEXNET, VGG16
 from repro.kernels.conv.ops import (conv_direct_chwn, conv_im2col_nchw_fused,
                                     conv_stack)
 from repro.kernels.pool.ops import pool_chwn, pool_nchw
@@ -118,13 +118,9 @@ def test_calibration_proxy_shapes_compile(one_chip, engine):
                                                 jnp.float32))
 
 
-def test_alexnet_forward_fused_compiles_at_bucket_128(one_chip):
-    """The whole fp32 program served at bucket 128: CCCCC, pools fused
-    into conv1/conv2/conv5, the conv3->conv4 stack, the fused softmax."""
-    cfg = ALEXNET.replace(batch=128)
-    plan = plan_network_fused(cfg)
-    assert plan.conv_signature == "CCCCC"
-    assert any(op.stack_index is not None for op in plan.ops)
+def _compile_forward(cfg, plan, one_chip):
+    """The fused fp32 forward of ``cfg`` at its batch, compiled from
+    shapes alone."""
     params = jax.eval_shape(lambda k: init_cnn(k, cfg),
                             jax.random.PRNGKey(0))
     params = jax.tree.map(
@@ -132,7 +128,31 @@ def test_alexnet_forward_fused_compiles_at_bucket_128(one_chip):
         params)
     x = jax.ShapeDtypeStruct(input_shape(cfg), jnp.float32,
                              sharding=one_chip)
-    compiled = jax.jit(lambda p, x: forward_fused(
+    return jax.jit(lambda p, x: forward_fused(
         p, x, cfg, plan, impl="pallas", interpret=False)[0]).lower(
             params, x).compile()
+
+
+def test_alexnet_forward_fused_compiles_at_bucket_128(one_chip):
+    """The whole fp32 program served at bucket 128: CCCCC, pools fused
+    into conv1/conv2/conv5, the conv3->conv4 stack, the fused softmax."""
+    cfg = ALEXNET.replace(batch=128)
+    plan = plan_network_fused(cfg)
+    assert plan.conv_signature == "CCCCC"
+    assert any(op.stack_index is not None for op in plan.ops)
+    compiled = _compile_forward(cfg, plan, one_chip)
     assert compiled.as_text().count("tpu_custom_call") == 5
+
+
+def test_vgg16_forward_fused_compiles_at_bucket_32(one_chip):
+    """VGG-16 (configuration D, 224 px) served at bucket 32: per-sample
+    NCHW convs on whole 224x224 planes, the conv1, conv2 and conv3_1->3_2
+    stacks, every pool fused; one kernel per conv op and the softmax."""
+    cfg = VGG16.replace(batch=32)
+    plan = plan_network_fused(cfg)
+    assert plan.conv_signature == "N" * 13
+    assert sum(op.stack_index is not None for op in plan.ops) == 3
+    kernels = sum(op.kind in ("conv", "pool", "softmax") for op in plan.ops)
+    assert kernels == 11
+    compiled = _compile_forward(cfg, plan, one_chip)
+    assert compiled.as_text().count("tpu_custom_call") == kernels
